@@ -16,9 +16,8 @@ from repro.launch.runtime import maybe_enable_compilation_cache
 from repro.models.cnn import CNNConfig, cnn_accuracy, cnn_loss, init_cnn
 from repro.sanitize import compile_budget, install_compile_listener
 
-# persistent XLA cache, ON by default: repeat benchmark runs skip
-# compiles (REPRO_COMPILATION_CACHE=off opts out; the measured regions
-# all warm up first, so timings are unaffected either way)
+# persistent XLA cache: repeat benchmark runs skip compiles (the
+# measured regions all warm up first, so timings are unaffected)
 maybe_enable_compilation_cache()
 # compile-event counter: time_call() asserts its measured repeats hit
 # the dispatch cache — a benchmark that recompiles mid-measurement is

@@ -21,6 +21,7 @@ from repro.core.engine import ENGINES, engine_config
 from repro.core.types import TrainConfig
 from repro.data.pipeline import IDPADataset
 from repro.data.synthetic import image_dataset
+from repro.launch import runtime
 from repro.models.cnn import CNNConfig, cnn_accuracy, cnn_loss, init_cnn
 
 
@@ -52,6 +53,8 @@ def main(argv=None):
 
     if args.small:
         args.fc_neurons, args.image_size, args.steps = 256, 16, 60
+    print(f"[bpt-cnn] compilation cache: "
+          f"{runtime.maybe_enable_compilation_cache()}")
 
     cfg = CNNConfig(name="case2-wide", image_size=args.image_size,
                     conv_layers=4, filters=4, fc_layers=3,

@@ -70,9 +70,9 @@ class TrainReport:
     # (sync baseline).  The device path falls back to "vmap" when the
     # backend has too few devices — callers can assert on this.
     backend: str = ""
-    # non-empty when the executed backend differs from the requested one
-    # (the EnginePlan's recorded device-count fallback reason)
-    fallback: str = ""
+    # the EnginePlan's recorded device-count fallback reason when the
+    # executed backend differs from the requested one, else None
+    fallback: Optional[str] = None
     # global index just past the last event (= its round + 1); differs
     # from ``steps`` when the run resumed from a state checkpoint, where
     # ``steps`` counts only the events this process produced

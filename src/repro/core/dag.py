@@ -20,6 +20,8 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from repro.kernels import LANE, MAX_TILE
+
 __all__ = [
     "Task", "TaskDAG", "conv_layer_tasks", "cnn_training_dag",
     "priority_schedule", "ScheduleResult", "conv_output_shape",
@@ -199,35 +201,36 @@ def conv_grid_tasks(dag: TaskDAG, batch: int, cout: int, oc_tile: int,
             for b in range(batch) for c in range(0, cout, oc_tile)]
 
 
-@functools.lru_cache(maxsize=None)
-def choose_oc_tile(batch: int, cout: int, workers: int = 8,
-                   min_tile: int = 8) -> int:
-    """Pick the output-channel tile for the executed conv grid (PT_Conv).
-
-    For every candidate tile (divisors of ``cout`` no smaller than
-    ``min_tile``, clamped to ``cout``) the candidate task grid is built with
-    :func:`conv_grid_tasks` and list-scheduled with Alg. 4.2
-    (:func:`priority_schedule`) over ``workers`` threads; the tile with the
-    minimal makespan wins, larger tiles breaking ties (fewer, bigger
-    MXU-friendly tasks).  Task decomposition and the executed Pallas grid
-    stay one concept: the kernels run exactly the grid this model scores.
-
-    ``min_tile`` keeps tiles lane-friendly on TPU — per-filter scalar tasks
-    (the paper's CPU/GPU granularity) waste the 128-wide MXU lanes.
-    """
-    if batch < 1 or cout < 1:
-        raise ValueError("batch and cout must be >= 1")
-    floor = min(cout, max(1, min_tile))
-    best_tile, best_makespan = cout, float("inf")
-    for tile in range(cout, floor - 1, -1):
-        if cout % tile:
-            continue
+def _best_tile(candidates: Iterable[int], grid, workers: int) -> int:
+    """Alg. 4.2 over candidate tiles: the minimal-makespan tile wins,
+    larger tiles breaking ties (fewer, bigger MXU-friendly tasks)."""
+    best_tile, best_makespan = 0, float("inf")
+    for tile in sorted(set(candidates), reverse=True):
         dag = TaskDAG()
-        conv_grid_tasks(dag, batch, cout, tile)
+        grid(dag, tile)
         makespan = priority_schedule(dag, workers).makespan
         if makespan < best_makespan - 1e-9:
             best_tile, best_makespan = tile, makespan
     return best_tile
+
+
+@functools.lru_cache(maxsize=None)
+def choose_oc_tile(batch: int, cout: int, workers: int = 8) -> int:
+    """Pick the output-channel tile for the executed conv grid (PT_Conv).
+
+    For every TPU-legal candidate tile — ``cout`` itself, or a multiple
+    of ``kernels.LANE`` dividing it — the candidate task grid is built
+    with :func:`conv_grid_tasks` and list-scheduled with
+    Alg. 4.2 (:func:`priority_schedule`) over ``workers`` threads; the
+    tile with the minimal makespan wins, larger tiles breaking ties.
+    Task decomposition and the executed Pallas grid stay one concept: the
+    kernels run exactly the grid this model scores.
+    """
+    if batch < 1 or cout < 1:
+        raise ValueError("batch and cout must be >= 1")
+    cands = [cout] + [t for t in range(LANE, cout, LANE) if cout % t == 0]
+    return _best_tile(cands, lambda dag, t: conv_grid_tasks(
+        dag, batch, cout, t), workers)
 
 
 def fc_grid_tasks(dag: TaskDAG, d_out: int, block: int,
@@ -236,47 +239,43 @@ def fc_grid_tasks(dag: TaskDAG, d_out: int, block: int,
     """The TPU-executed FC task list: one task per output-neuron block.
 
     This is the paper's §4.1.2 G_FC granularity expressed at the grid the
-    Pallas dense kernel actually runs — (d_out/block,), each cell one
-    ``(B, Din) x (Din, block)`` matmul task (the whole batch lives in one
-    cell, unlike the conv grid's batch axis).  All tasks are mutually
-    independent; each costs ``block * cost_per_neuron``.
+    Pallas dense kernel actually runs — ceil(d_out/block) cells, each one
+    ``(B, Din) x (Din, block)`` matmul task.  A ``LANE``-multiple block
+    need not divide ``d_out``: the kernel pads the layer up to whole
+    blocks, so every task costs ``block * cost_per_neuron`` (padded lanes
+    compute too).  All tasks are mutually independent.
     """
-    if block <= 0 or d_out % block:
-        raise ValueError(f"block {block} must divide d_out {d_out}")
+    if block <= 0 or (d_out % block and block % LANE):
+        raise ValueError(
+            f"block {block} must divide d_out {d_out} or be a multiple "
+            f"of {LANE}")
     cost = block * cost_per_neuron
     return [dag.add(f"{name}[{n}]", cost, deps)
             for n in range(0, d_out, block)]
 
 
 @functools.lru_cache(maxsize=None)
-def choose_fc_block(d_out: int, workers: int = 8, min_block: int = 8) -> int:
+def choose_fc_block(d_out: int, workers: int = 8) -> int:
     """Pick the output-neuron block for the executed dense grid (G_FC).
 
-    The ``choose_oc_tile`` sibling for the FC stack: every candidate block
-    (divisors of ``d_out`` no smaller than ``min_block``, clamped to
-    ``d_out``) builds its task grid with :func:`fc_grid_tasks` and is
-    list-scheduled with Alg. 4.2 (:func:`priority_schedule`) over
-    ``workers`` threads; the block with the minimal makespan wins, larger
-    blocks breaking ties (fewer, bigger MXU-friendly tasks).  The dense
-    kernel runs exactly the grid this model scores — decomposition and
-    executed grid stay one concept.
-
-    ``min_block`` keeps blocks lane-friendly on TPU — per-neuron scalar
-    tasks (the paper's CPU/GPU granularity) waste the 128-wide MXU lanes.
+    The ``choose_oc_tile`` sibling for the FC stack.  The candidates are
+    the blocks the TPU lowering accepts: multiples of ``LANE`` up to
+    ``MAX_TILE`` (the widest weight panel a dense grid cell holds in
+    VMEM, and no wider than the layer padded to ``LANE``), plus the whole
+    layer when it is at most ``MAX_TILE`` wide.  Each builds its task
+    grid with :func:`fc_grid_tasks` and is list-scheduled with Alg. 4.2
+    over ``workers`` threads; the block with the minimal makespan wins,
+    larger blocks breaking ties.  The dense kernel runs exactly the grid
+    this model scores — decomposition and executed grid stay one concept.
     """
     if d_out < 1:
         raise ValueError("d_out must be >= 1")
-    floor = min(d_out, max(1, min_block))
-    best_block, best_makespan = d_out, float("inf")
-    for block in range(d_out, floor - 1, -1):
-        if d_out % block:
-            continue
-        dag = TaskDAG()
-        fc_grid_tasks(dag, d_out, block)
-        makespan = priority_schedule(dag, workers).makespan
-        if makespan < best_makespan - 1e-9:
-            best_block, best_makespan = block, makespan
-    return best_block
+    top = min(MAX_TILE, -(-d_out // LANE) * LANE)
+    cands = list(range(LANE, top + 1, LANE))
+    if d_out <= MAX_TILE:
+        cands.append(d_out)
+    return _best_tile(cands, lambda dag, b: fc_grid_tasks(dag, d_out, b),
+                      workers)
 
 
 # ----------------------------------------------------------------------

@@ -121,7 +121,7 @@ class EnginePlan:
     strategy: str              # sync|sgwu|agwu
     requested: str             # backend the config asked for
     mesh: Any = None           # the `nodes` mesh (ShardMapEngine only)
-    fallback: str = ""         # "" unless backend != requested
+    fallback: Optional[str] = None   # the reason, iff backend != requested
     devices: Any = None        # the device pool the plan was resolved
                                # against (HeapDeviceEngine pins node j to
                                # devices[j]; ShardMapEngine via ``mesh``)

@@ -6,7 +6,15 @@ Kernel modules (`conv2d`, `flash_attention`, `rmsnorm`) expose raw
 """
 from __future__ import annotations
 
-__all__ = ["resolve_interpret"]
+__all__ = ["resolve_interpret", "LANE", "MAX_TILE"]
+
+# The TPU's vector lane width.  The Pallas TPU lowering accepts a block
+# whose minor dimension is a multiple of LANE or the whole array axis —
+# every tile the Alg. 4.2 choosers offer (``core.dag``) obeys this rule.
+LANE = 128
+# Widest lane tile a dense grid cell takes (its K and neuron-block axes):
+# bounds the cell's weight panel in VMEM (see ``kernels.dense``).
+MAX_TILE = 4 * LANE
 
 
 def resolve_interpret(interpret):

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import resolve_interpret
+from repro.kernels import LANE, resolve_interpret
 
 __all__ = ["conv2d_pallas"]
 
@@ -205,7 +205,35 @@ def _backward_dx(g, w, x_shape, out_dtype, *, padding: str, oc_tile: int,
     )(gp, wf)
 
 
-_DW_BATCH_TILE = 8     # VMEM cap for the dw kernel's per-cell batch slice
+# VMEM the dw kernel's batch slice may take per grid cell: the
+# double-buffered input and cotangent blocks plus the kernel's 2-D
+# (Bt*H*W, C) operands.
+_DW_VMEM_BUDGET = 8 * 2**20
+
+
+def _vmem_bytes(shape, itemsize: int) -> int:
+    """Bytes of one VMEM buffer: its minor two axes pad to (8, LANE)."""
+    *lead, sub, lane = shape
+    return (math.prod(lead) * (-(-sub // 8) * 8)
+            * (-(-lane // LANE) * LANE) * itemsize)
+
+
+def _dw_batch_tile(B: int, in_block, g_block, itemsize: int) -> int:
+    """The largest divisor of B whose dw cell fits ``_DW_VMEM_BUDGET``.
+
+    ``in_block``/``g_block`` are one image's input and cotangent block
+    shapes.  A minor axis of 3 or 12 channels pads to a full LANE, so
+    early layers take few images per cell and deep, small maps many.
+    Never below one image.
+    """
+    cin = in_block[-1]
+    H, W, ct = g_block
+    per_image = (2 * (_vmem_bytes(in_block, itemsize)
+                      + _vmem_bytes(g_block, itemsize))
+                 + _vmem_bytes((H * W, cin), itemsize)
+                 + _vmem_bytes((H * W, ct), itemsize))
+    fit = max(1, _DW_VMEM_BUDGET // per_image)
+    return max(d for d in range(1, min(B, fit) + 1) if B % d == 0)
 
 
 def _backward_dw(x, g, w_shape, *, padding: str, oc_tile: int,
@@ -213,8 +241,8 @@ def _backward_dw(x, g, w_shape, *, padding: str, oc_tile: int,
     """dL/dw over the per-filter G_Conv grid.
 
     Grid (Cout/oc_tile, B/Bt): one output block per filter tile, revisited
-    along the sequential batch axis so VMEM holds at most a
-    ``_DW_BATCH_TILE``-image slice instead of the whole batch.
+    along the sequential batch axis so VMEM holds a Bt-image slice
+    (``_dw_batch_tile``) instead of the whole batch.
     """
     B, H, W, Cin = x.shape
     kh, kw, _, Cout = w_shape
@@ -227,9 +255,8 @@ def _backward_dw(x, g, w_shape, *, padding: str, oc_tile: int,
         xp = x
         Ho, Wo = H - kh + 1, W - kw + 1
     ct = oc_tile or Cout
-    # largest power-of-2 divisor of B up to the cap: the VMEM bound holds
-    # for every batch size (odd B degrades to bt=1, never to bt=B)
-    bt = math.gcd(B, _DW_BATCH_TILE)
+    bt = _dw_batch_tile(B, (Ho + kh - 1, Wo + kw - 1, Cin), (Ho, Wo, ct),
+                        x.dtype.itemsize)
 
     return pl.pallas_call(
         functools.partial(_conv_dw_kernel, kh=kh, kw=kw, H=Ho, W=Wo),

@@ -13,9 +13,9 @@ Kernel entry points take ``interpret=None`` and resolve it through
 ``_interpret()`` here — the single switch that decides interpret-vs-compiled
 — so no call site can silently ship interpret-mode kernels to a TPU.
 
-**Fallback contract**: when the pallas impl cannot serve a call (e.g. a
-strided conv, overlapping pooling, a dense cell too large for VMEM) the
-fallback to the jnp ref is never silent.  An explicit ``impl="pallas"``
+**Fallback contract**: when the pallas impl cannot serve a call (a
+strided conv, overlapping pooling) the fallback to the jnp ref is never
+silent.  An explicit ``impl="pallas"``
 argument raises ``NotImplementedError``; an environment/default-selected
 pallas impl warns once per (op, reason) with ``KernelFallbackWarning`` and
 records the event in ``fallback_events()`` — tests assert the log stays
@@ -176,30 +176,6 @@ def max_pool2d(x, window: int = 2, stride: int = 2, impl: str = ""):
     return ref.max_pool2d_ref(x, window=window, stride=stride)
 
 
-# Per-grid-cell VMEM budget for the dense kernel (bytes).  The kernel
-# holds the whole flattened row block, one weight panel and one output
-# panel per cell — fine for the paper's FC stacks, but a transformer-scale
-# matmul (e.g. an LM head) would blow the ~16 MB VMEM; those calls take
-# the explicit-fallback contract until the kernel grows row/K tiling.
-_DENSE_VMEM_BUDGET = 8 * 2**20
-
-
-def _dense_cell_bytes(rows: int, d_in: int, d_out: int, block: int,
-                      itemsize: int) -> int:
-    """Worst per-cell VMEM footprint across the three dense grids.
-
-    fwd/dwdb cells hold the row block, one (Din, block) weight panel and
-    one (rows, block) activation panel; the dx cell holds the full
-    cotangent row block plus a (Dout, it) transposed-weight panel, where
-    ``it`` is the derived Din tile (see ``dense._block_of``).
-    """
-    nt = block or d_out
-    it = block if (block and d_in % block == 0) else d_in
-    fwd = rows * d_in + d_in * nt + rows * nt
-    dx = rows * d_out + d_out * it + rows * it
-    return max(fwd, dx) * itemsize
-
-
 def dense(x, w, b=None, activation: str = "none", impl: str = "",
           block: int | None = None):
     """Fused dense layer: x @ w (+ b) (+ activation), paper §4.1.2.
@@ -208,9 +184,9 @@ def dense(x, w, b=None, activation: str = "none", impl: str = "",
     row axis and reshape back.  The Pallas path is differentiable via
     ``custom_vjp`` (per-block G_FC gradient tasks); ``block=None`` asks
     the Alg. 4.2 cost model (``core.dag.choose_fc_block``) for the task
-    granularity, ``block=0`` forces one task for the whole layer.  A call
-    whose grid cell would exceed ``_DENSE_VMEM_BUDGET`` takes the
-    explicit-fallback contract.
+    granularity, ``block=0`` forces one task for the whole layer.  The
+    kernel tiles rows and the contraction itself, so every width is
+    served — there is no dense fallback.
 
     Under an active plan scope a ``channel``-parallel LayerPlan reroutes
     the call through the Megatron column dataflow: the weight/bias shard
@@ -233,30 +209,17 @@ def dense(x, w, b=None, activation: str = "none", impl: str = "",
                 out = dense(xr, ws, bs, activation=activation, impl=impl,
                             block=block)
                 return planner.gather_cols(out, lp.shards, lp.axis)
-    explicit = impl == "pallas"
     impl = impl or default_impl()
     if impl == "pallas":
         if block is None:
             from repro.core.dag import choose_fc_block
             block = choose_fc_block(int(w.shape[-1]))
-        rows = 1
-        for d in x.shape[:-1]:
-            rows *= int(d)
-        cell = _dense_cell_bytes(rows, int(x.shape[-1]), int(w.shape[-1]),
-                                 int(block), x.dtype.itemsize)
-        if cell <= _DENSE_VMEM_BUDGET:
-            lead = x.shape[:-1]
-            x2 = x.reshape(-1, x.shape[-1])
-            # match the ref's compute dtype (w cast to the activations')
-            out = dense_pallas(x2, w.astype(x.dtype), b,
-                               activation=activation, block=block,
-                               interpret=_interpret())
-            return out.reshape(*lead, w.shape[-1])
-        _fallback(
-            "dense",
-            f"grid cell of {cell / 2**20:.1f} MiB exceeds the "
-            f"{_DENSE_VMEM_BUDGET / 2**20:.0f} MiB VMEM budget "
-            "(kernel has no row/K tiling yet)", explicit)
+        lead = x.shape[:-1]
+        x2 = x.reshape(-1, x.shape[-1])
+        # match the ref's compute dtype (w cast to the activations')
+        out = dense_pallas(x2, w.astype(x.dtype), b, activation=activation,
+                           block=block, interpret=_interpret())
+        return out.reshape(*lead, w.shape[-1])
     return ref.dense_ref(x, w, b, activation=activation)
 
 

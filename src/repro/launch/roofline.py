@@ -148,8 +148,6 @@ def analyze_compiled(compiled, lowered_text: Optional[str], arch: str,
                      shape_name: str, mesh_name: str, chips: int,
                      cfg=None, shape=None) -> RooflineReport:
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):          # older jax returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     text = lowered_text if lowered_text is not None else compiled.as_text()

@@ -1,12 +1,15 @@
 """Process-level runtime knobs shared by drivers and benchmarks.
 
-Currently one knob: the persistent XLA compilation cache (the ROADMAP
-perf-flywheel item).  ON BY DEFAULT for drivers and benchmarks — repeat
-runs of the same driver skip recompiles entirely because identical HLO
-hits the on-disk cache instead of XLA.  ``REPRO_COMPILATION_CACHE``
-overrides: a path relocates the cache, ``off`` (or ``0``) disables it.
-Tests never call ``maybe_enable_compilation_cache``, so the suite keeps
-its hermetic no-cache behavior.
+Currently one knob: JAX's persistent compilation cache.  Drivers and
+benchmarks call ``maybe_enable_compilation_cache`` once at start-up, so a
+repeat run of the same program reads its compiled executables back
+instead of compiling again.  Where ``JAX_COMPILATION_CACHE_DIR`` is set,
+JAX already uses that directory and nothing here overrides it; otherwise
+the cache lives at one fixed path inside the checkout (``.jax_cache/``,
+gitignored) — a fixed path, because the directory is part of what makes
+a later run find the entries.  Of the tests, only ``tests/test_runtime.py``
+calls it, and it restores the config afterwards, so the suite keeps its
+hermetic no-cache behavior.
 """
 from __future__ import annotations
 
@@ -14,31 +17,27 @@ import os
 
 __all__ = ["maybe_enable_compilation_cache", "default_cache_dir"]
 
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+
 
 def default_cache_dir() -> str:
-    """XDG-style default cache location (``~/.cache`` unless overridden)."""
-    base = os.environ.get("XDG_CACHE_HOME",
-                          os.path.join(os.path.expanduser("~"), ".cache"))
-    return os.path.join(base, "repro", "xla")
+    """The checkout's own cache directory: ``<repo>/.jax_cache``."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+    return os.path.join(root, ".jax_cache")
 
 
 def maybe_enable_compilation_cache() -> str:
-    """Enable jax's persistent compilation cache (default ON).
+    """Turn on JAX's persistent compilation cache; return its directory.
 
-    Returns the cache directory actually enabled — the
-    ``REPRO_COMPILATION_CACHE`` path when set, ``default_cache_dir()``
-    when unset, or "" when the knob is ``off``/``0``.  Safe to call more
-    than once and before/after other jax work; the directory is created
-    if missing.
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, so its
+    setting is left alone.  Unset: ``default_cache_dir()``, created if
+    missing.  Safe to call more than once.
     """
-    knob = os.environ.get("REPRO_COMPILATION_CACHE", "")
-    if knob.lower() in ("off", "0"):
-        return ""
-    path = knob or default_cache_dir()
-    from jax.experimental.compilation_cache import compilation_cache as cc
+    import jax
+    if os.environ.get(_ENV):
+        return jax.config.jax_compilation_cache_dir
+    path = default_cache_dir()
     os.makedirs(path, exist_ok=True)
-    if hasattr(cc, "set_cache_dir"):
-        cc.set_cache_dir(path)
-    else:                       # older jax spelling
-        cc.initialize_cache(path)
+    jax.config.update("jax_compilation_cache_dir", path)
     return path

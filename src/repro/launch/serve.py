@@ -52,11 +52,10 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    # persistent XLA cache (default on): repeat serve runs skip the
-    # prefill/decode compiles entirely (REPRO_COMPILATION_CACHE=off opts out)
-    cache_dir = runtime.maybe_enable_compilation_cache()
-    if cache_dir:
-        print(f"[serve] compilation cache: {cache_dir}")
+    # persistent XLA cache: repeat serve runs skip the prefill/decode
+    # compiles (JAX_COMPILATION_CACHE_DIR, else the checkout's .jax_cache)
+    print(f"[serve] compilation cache: "
+          f"{runtime.maybe_enable_compilation_cache()}")
 
     cfg = configs.get_reduced(args.arch)
     # params and prompt stream draw from SPLIT keys (the old demo reused

@@ -93,9 +93,8 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    cache_dir = runtime.maybe_enable_compilation_cache()
-    if cache_dir:
-        print(f"[train] compilation cache: {cache_dir}")
+    print(f"[train] compilation cache: "
+          f"{runtime.maybe_enable_compilation_cache()}")
 
     cfg = configs.get_reduced(args.arch) if args.reduced \
         else configs.get_config(args.arch)

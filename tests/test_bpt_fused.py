@@ -154,8 +154,7 @@ class TestAgwuGammaRegression:
         round-trip, which is the very noise (and cost) the rewrite
         removes; the f32 agreement is checked separately below.
         """
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64():
             for k, latest, out in _VERSION_GRID:
                 old = _agwu_gamma_seed_impl(k, latest, out)
                 new = agwu_gamma(k, latest, out)

@@ -8,6 +8,7 @@ from repro.core.dag import (TaskDAG, choose_fc_block, choose_oc_tile,
                             cnn_training_dag, conv_grid_tasks,
                             conv_layer_tasks, conv_output_shape,
                             fc_grid_tasks, priority_schedule)
+from repro.kernels import LANE
 
 
 class TestConvDecomposition:
@@ -47,13 +48,19 @@ class TestExecutedGrid:
             assert cout % tile == 0 and tile >= 1
 
     def test_small_cout_stays_untiled(self):
-        # below min_tile the MXU-lane floor keeps one task per image
+        # a narrow cout has no lane-legal split: one task per image
         assert choose_oc_tile(4, 4) == 4
 
     def test_wide_conv_tiles_to_fill_workers(self):
-        # B=1, Cout=128, 8 workers: untiled = 1 task (makespan 128);
-        # tile 16 = 8 tasks in parallel (makespan 16) — the model must tile.
-        assert choose_oc_tile(1, 128, workers=8) == 16
+        # B=1, Cout=1024, 8 workers: untiled = 1 task (makespan 1024);
+        # tile 128 = 8 tasks in parallel (makespan 128) — the model must
+        # tile, and 128 is the narrowest lane-legal tile it may offer.
+        assert choose_oc_tile(1, 1024, workers=8) == 128
+
+    def test_narrow_cout_is_never_split_below_a_lane(self):
+        # Cout=128 at B=1 would schedule best as 16-wide tiles, but a
+        # 16-channel block is no TPU-legal lane tile: the layer stays whole
+        assert choose_oc_tile(1, 128, workers=8) == 128
 
     def test_saturated_batch_prefers_big_tiles(self):
         # B=64 images already saturate 8 workers; splitting channels only
@@ -87,18 +94,59 @@ class TestFCBlockModel:
     @settings(max_examples=30, deadline=None)
     @given(d_out=st.integers(1, 2048), workers=st.integers(1, 16))
     def test_choose_block_divides_d_out(self, d_out, workers):
+        # the kernel pads the layer up to whole blocks, so a block either
+        # IS the layer or is a lane multiple the padded layer divides
         block = choose_fc_block(d_out, workers=workers)
-        assert d_out % block == 0 and block >= 1
+        assert block == d_out or (block % LANE == 0
+                                  and block <= -(-d_out // LANE) * LANE)
 
     def test_small_d_out_stays_whole(self):
-        # below min_block the MXU-lane floor keeps one task per layer
+        # a layer narrower than a lane runs whole (a full-axis block)
         assert choose_fc_block(4) == 4
-        assert choose_fc_block(10) == 10     # no divisor in [8, 10)
+        assert choose_fc_block(10) == 10     # not a padded 128-lane block
 
     def test_wide_fc_blocks_to_fill_workers(self):
-        # d_out=128, 8 workers: one whole-layer task = makespan 128;
-        # block 16 = 8 parallel tasks (makespan 16) — the model must split.
-        assert choose_fc_block(128, workers=8) == 16
+        # d_out=1024, 8 workers: block 512 = 2 tasks (makespan 512);
+        # block 128 = 8 parallel tasks (makespan 128) — the model must split.
+        assert choose_fc_block(1024, workers=8) == 128
+
+    def test_padded_grid_counts_whole_blocks(self):
+        # a lane-multiple block need not divide d_out: the executed grid
+        # pads the layer up to ceil(d_out / block) full-cost tasks
+        dag = TaskDAG()
+        tids = fc_grid_tasks(dag, d_out=500, block=128)
+        assert len(tids) == 4
+        assert all(dag.tasks[t].cost == 128.0 for t in tids)
+
+    @pytest.mark.parametrize("case", [f"case{i}" for i in range(1, 8)])
+    def test_table2_blocks_are_tpu_legal(self, case):
+        """Every tile the choosers can hand a Table-2 layer — the FC
+        block, the dx grid's derived Din tile, the conv oc tile — is a
+        LANE multiple or the whole axis (the Pallas TPU lowering refuses
+        anything else)."""
+        from repro.kernels.dense import _block_of
+        from repro.models.cnn import _conv_shapes, make_case
+        cfg = make_case(case)
+        shapes, final = _conv_shapes(cfg)
+        dims = ([final * final * cfg.filters]
+                + [cfg.fc_neurons] * (cfg.fc_layers - 1) + [cfg.num_classes])
+        for d_in, d_out in zip(dims[:-1], dims[1:]):
+            for workers in (1, 4, 8, 16):
+                block = choose_fc_block(d_out, workers=workers)
+                assert block == d_out or block % LANE == 0, (d_out, block)
+                tile = _block_of(d_in, block)
+                assert tile == d_in or tile % LANE == 0, (d_in, tile)
+        for batch in (1, 16, 64):
+            tile = choose_oc_tile(batch, cfg.filters)
+            assert tile == cfg.filters or tile % LANE == 0
+
+    @settings(max_examples=60, deadline=None)
+    @given(features=st.integers(1, 5000), d_out=st.integers(1, 5000))
+    def test_derived_din_tile_is_tpu_legal(self, features, d_out):
+        from repro.kernels.dense import _block_of
+        for block in (0, choose_fc_block(d_out)):
+            tile = _block_of(features, block)
+            assert tile == features or tile % LANE == 0
 
     def test_single_worker_prefers_whole_layer(self):
         # serial makespans all equal d_out; the largest block wins the tie

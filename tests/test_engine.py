@@ -239,7 +239,7 @@ class TestReportSurface:
         tr = _make_trainer(m=2, **engine_config(name))
         rep = tr.train(rounds=2)
         assert rep.backend == name
-        assert rep.fallback == ""
+        assert rep.fallback is None
         assert "fallback" not in rep.summary()
 
     @pytest.mark.parametrize("name", ["device", "heap-device"])
@@ -247,7 +247,7 @@ class TestReportSurface:
     def test_device_backends_recorded(self, name):
         tr = _make_trainer(m=2, **engine_config(name))
         rep = tr.train(rounds=2)
-        assert rep.backend == name and rep.fallback == ""
+        assert rep.backend == name and rep.fallback is None
 
     def test_fallback_surfaced(self):
         m = 2 * NDEV

@@ -116,6 +116,8 @@ def _dense_grid():
         (4, 8, "relu", True, (2, 16, 8)),    # block == Dout
         (5, 2, "none", False, (1, 6, 10)),   # no bias, odd dims
         (6, 5, "relu", False, (3, 7, 10)),   # Din not divisible by block
+        (7, 128, "relu", True, (5, 40, 200)),    # lane block: Dout pads
+        (8, 128, "none", True, (4, 300, 130)),   # dx reuses it: Din pads
     ]
 
 
@@ -291,25 +293,36 @@ class TestFallbackContract:
                 ops.max_pool2d(x, window=3, stride=1)
         assert any(op == "max_pool2d" for op, _ in ops.fallback_events())
 
-    def test_explicit_pallas_oversized_dense_raises(self):
-        """A grid cell past the VMEM budget cannot be served: the kernel
-        has no row/K tiling, so a transformer-scale matmul must not be
-        silently attempted (or silently ref'd)."""
-        x = jnp.ones((9000, 256), jnp.float32)       # ~9.4 MiB cell
-        w = jnp.ones((256, 8), jnp.float32)
-        with pytest.raises(NotImplementedError, match="VMEM budget"):
-            ops.dense(x, w, impl="pallas")
+    def test_explicit_pallas_wide_dense_is_tiled(self):
+        """A matmul far wider than one VMEM cell is served by the kernel
+        itself — rows and the contraction tile and pad — never by the
+        ref: no raise, no recorded fallback, the ref's answer."""
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(29), 3)
+        x = rand(k1, (300, 600))          # rows > 256, K > 512: both tile
+        w = rand(k2, (600, 200))          # Dout pads to the 128 block
+        b = rand(k3, (200,))
+        got = ops.dense(x, w, b, activation="relu", impl="pallas")
+        want = ops.dense(x, w, b, activation="relu", impl="ref")
+        np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+        assert ops.fallback_events() == {}
 
-    def test_env_pallas_oversized_dense_warns_and_uses_ref(self,
-                                                          monkeypatch):
+    def test_env_pallas_wide_dense_grads_match_ref(self, monkeypatch):
         monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas")
-        x = jnp.ones((9000, 256), jnp.float32)
-        w = jnp.ones((256, 8), jnp.float32)
-        with pytest.warns(ops.KernelFallbackWarning, match="VMEM budget"):
-            got = ops.dense(x, w)
-        np.testing.assert_allclose(got, ops.dense(x, w, impl="ref"),
-                                   atol=1e-5)
-        assert any(op == "dense" for op, _ in ops.fallback_events())
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(31), 3)
+        x = rand(k1, (264, 520))
+        w = rand(k2, (520, 130))
+        cot = rand(k3, (264, 130))
+
+        def loss(impl):
+            return lambda x_, w_: jnp.sum(ops.dense(x_, w_, impl=impl) * cot)
+
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            got = jax.grad(loss(""), argnums=(0, 1))(x, w)
+        want = jax.grad(loss("ref"), argnums=(0, 1))(x, w)
+        for g, r in zip(got, want, strict=True):
+            np.testing.assert_allclose(g, r, atol=1e-3, rtol=1e-4)
+        assert ops.fallback_events() == {}
 
     def test_dense_mixed_precision_matches_ref_dtype_path(self):
         """bf16 activations with f32 master weights: the pallas dispatch
