@@ -43,11 +43,11 @@ import numpy as np
 
 from repro.checkpointing import checkpoint
 from repro.data.pipeline import IDPADataset
-from repro.sanitize import sanctioned_scope, sanctioned_sync
+from repro.sanitize import sanctioned_scope, sanctioned_sync, span
 from repro.optim.optimizers import (apply_updates, clip_by_global_norm,
                                     make_optimizer, warmup_cosine)
 
-from .engine import RoundEvent, TrainHooks, resolve_engine
+from .engine import RoundEvent, TrainHooks, place_batches, resolve_engine
 from .types import TrainConfig
 
 __all__ = ["BPTTrainer", "TrainReport", "TrainHooks", "RoundEvent"]
@@ -220,9 +220,10 @@ class BPTTrainer:
             batch = self.dataset.node_batch(node, self.batch_size, self.rng)
             # one explicit placement for batch + step scalar: the train
             # step dispatch never uploads implicitly (transfer-guard clean)
-            batch, step_dev = jax.device_put((batch, np.int32(step)))
-            params, opt_state, loss = self._train_step(
-                params, opt_state, batch, step_dev)
+            batch, step_dev = place_batches((batch, np.int32(step)))
+            with span("dispatch"):
+                params, opt_state, loss = self._train_step(
+                    params, opt_state, batch, step_dev)
         # Eq. 8 measurement boundary — a sanctioned sync, not a hidden one
         loss = float(sanctioned_sync(loss, "local-round.loss"))
         wall = time.perf_counter() - t0
@@ -352,8 +353,10 @@ class BPTTrainer:
                 ev.accuracy = self._eval(ev.params)
             if hooks.checkpoint_every and hooks.checkpoint_dir \
                     and n % hooks.checkpoint_every == 0:
-                checkpoint.save(hooks.checkpoint_dir, ev.params, step=n)
-                self._save_run_state(engine, state, hooks.checkpoint_dir, n)
+                with span("checkpoint", step=n):
+                    checkpoint.save(hooks.checkpoint_dir, ev.params, step=n)
+                    self._save_run_state(engine, state, hooks.checkpoint_dir,
+                                         n)
             if hooks.on_round:
                 hooks.on_round(ev)
             yield ev

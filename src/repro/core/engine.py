@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.launch.mesh import make_mesh, make_nodes_mesh
-from repro.sanitize import sanctioned_sync, sanitized
+from repro.sanitize import sanctioned_sync, sanitized, span
 
 from .gwu import broadcast_tree, tree_sub
 from .param_server import ParameterServer
@@ -51,6 +51,14 @@ __all__ = [
     "HeapEngine", "HeapDeviceEngine", "ENGINES", "engine_config",
     "resolve_engine",
 ]
+
+
+def place_batches(tree, sharding=None):
+    """One explicit placement of a round's host batches, as the span
+    ``bpt.place`` (its ``bytes``: the host leaves' total size)."""
+    nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(tree))
+    with span("place", bytes=int(nbytes)):
+        return jax.device_put(tree, sharding)
 
 
 # ----------------------------------------------------------------------
@@ -257,7 +265,8 @@ class OuterEngine:
             # (REPRO_SANITIZE=1): implicit host<->device transfers raise;
             # the event is yielded OUTSIDE the scope so consumers
             # (eval / checkpoint hooks) may pull freely
-            with sanitized(f"{self.backend}.run_round"):
+            with sanitized(f"{self.backend}.run_round"), \
+                    span("round", round=r, backend=self.backend):
                 ev = self.run_round(state, r)
             yield ev
 
@@ -320,13 +329,15 @@ class ScanEngine(OuterEngine):
                    for _ in range(t.tc.local_steps)]
         # stack on host, then ONE explicit placement — the jit dispatch
         # below never uploads implicitly (transfer-guard clean)
-        stacked = jax.device_put({k: np.stack([b[k] for b in batches])
-                                  for k in batches[0]})
+        stacked = place_batches({k: np.stack([b[k] for b in batches])
+                                 for k in batches[0]})
         # same contract as the stacked engines: the clock starts after the
         # host batch draw, so the virtual time is compute-only
         t0 = time.perf_counter()
-        st.params, st.opt_state, loss = t._scan_round(
-            st.params, st.opt_state, stacked, jax.device_put(np.int32(r)))
+        with span("dispatch"):
+            st.params, st.opt_state, loss = t._scan_round(
+                st.params, st.opt_state, stacked,
+                jax.device_put(np.int32(r)))
         loss = float(sanctioned_sync(loss, "scan.loss"))
         st.clock += (time.perf_counter() - t0) * t.speed[0]
         return RoundEvent(round=r, node_losses=np.asarray([loss]),
@@ -403,13 +414,15 @@ class _StackedSGWUEngine(OuterEngine):
         # explicit placement even on the fused single-device path
         # (batch_sharding None -> default device): the round dispatch
         # below must never upload the host batches implicitly
-        batches = jax.device_put(batches, st.batch_sharding)
+        batches = place_batches(batches, st.batch_sharding)
         # the Eq. 8 wall starts AFTER the host batch draw + device
         # placement: data prep is the main server's work, not node compute,
         # and must not pollute the sync-wait or the IDPA duration feedback
         t0 = time.perf_counter()
-        stacked_w, st.stacked_opt, node_losses = st.round_fn(
-            stacked_w, st.stacked_opt, batches, jax.device_put(np.int32(r)))
+        with span("dispatch"):
+            stacked_w, st.stacked_opt, node_losses = st.round_fn(
+                stacked_w, st.stacked_opt, batches,
+                jax.device_put(np.int32(r)))
         # the Eq. 8 measurement boundary: blocking here IS the wall
         # semantics, so the pull is a sanctioned sync, not a hidden one
         node_losses = sanctioned_sync(node_losses, "round.losses")
@@ -639,10 +652,11 @@ class HeapEngine(OuterEngine):
         return rounds * self.t.m
 
     def _pull(self, st, j):
-        w, _ = st.server.pull(j)
-        if self.device_nodes:
-            w = jax.device_put(w, self.plan.devices[j])
-            st.base_local[j] = w       # W(k) snapshot, node-resident
+        with span("pull"):
+            w, _ = st.server.pull(j)
+            if self.device_nodes:
+                w = jax.device_put(w, self.plan.devices[j])
+                st.base_local[j] = w       # W(k) snapshot, node-resident
         return w
 
     def setup(self, rounds):
@@ -763,7 +777,8 @@ class HeapEngine(OuterEngine):
                 # permanent failures: the dead nodes' rounds never run;
                 # the surviving nodes have completed all of theirs
                 return
-            with sanitized(f"{self.backend}.push"):
+            with sanitized(f"{self.backend}.push"), \
+                    span("round", round=i, backend=self.backend):
                 ev = self._process(st, i)
             if ev is None:
                 continue                    # dropped (lost) push

@@ -8,17 +8,32 @@ weight-set payload.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.sanitize import span
+
 from .gwu import (agwu_gamma, agwu_update, agwu_update_delta, broadcast_tree,
                   sgwu_merge, sgwu_merge_and_rebroadcast,
                   sgwu_merge_and_rebroadcast_sharded)
 
 __all__ = ["ParameterServer", "Submission"]
+
+
+def _in_span(name: str):
+    """Run a server method inside the span ``bpt.<name>``, whose
+    ``version`` is the server's version as the call starts."""
+    def wrap(method):
+        @functools.wraps(method)
+        def spanned(self, *args, **kwargs):
+            with span(name, version=self.version):
+                return method(self, *args, **kwargs)
+        return spanned
+    return wrap
 
 
 def _tree_bytes(tree) -> int:
@@ -82,6 +97,7 @@ class ParameterServer:
         self.comm_bytes += self.weight_bytes
         return self.global_weights, self.version
 
+    @_in_span("pull")
     def pull_all_stacked(self, active=None):
         """All m workers pull at once: one node-stacked replica tree.
 
@@ -125,6 +141,7 @@ class ParameterServer:
         agwu_update(self.global_weights, zeros, self.global_weights,
                     1.0, 1.0, donate_local=True)
 
+    @_in_span("merge")
     def push_agwu(self, worker: int, local_weights, accuracy: float,
                   virtual_time: float = 0.0, donate: bool = False):
         """AGWU: apply Eq. (10) immediately (1 transfer in).
@@ -153,6 +170,7 @@ class ParameterServer:
         self.update_log.append(Submission(worker, k, accuracy, virtual_time))
         return gamma
 
+    @_in_span("merge")
     def push_agwu_delta(self, worker: int, delta, accuracy: float,
                         virtual_time: float = 0.0):
         """AGWU push of a node-resident delta W_j(k) - W(k) (1 transfer in).
@@ -182,6 +200,7 @@ class ParameterServer:
         self.update_log.append(Submission(worker, k, accuracy, virtual_time))
         return gamma
 
+    @_in_span("merge")
     def push_sgwu(self, submissions: list[tuple[int, Any, float]],
                   virtual_time: float = 0.0):
         """SGWU: barrier-merge all workers' weights with Eq. (7).
@@ -212,6 +231,7 @@ class ParameterServer:
         self.num_updates += 1
         return self.global_weights
 
+    @_in_span("merge")
     def push_sgwu_stacked(self, stacked_weights,
                           accuracies: Sequence[float],
                           virtual_time: float = 0.0, active=None):

@@ -13,6 +13,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from repro.core.idpa import IDPAPartitioner, UDPAPartitioner
+from repro.sanitize import span
 
 __all__ = ["pack_sequences", "IDPADataset", "host_batch"]
 
@@ -76,10 +77,11 @@ class IDPADataset:
         """
         if self.part.done:
             return False
-        if isinstance(self.part, IDPAPartitioner):
-            self.part.next_batch(durations, active=active)
-        else:
-            self.part.next_batch(None, active=active)
+        with span("idpa"):
+            if isinstance(self.part, IDPAPartitioner):
+                self.part.next_batch(durations, active=active)
+            else:
+                self.part.next_batch(None, active=active)
         return True
 
     # -- crash-safe checkpointing: the partitioner's incremental state ---
@@ -108,8 +110,9 @@ class IDPADataset:
         return rng.choice(view, size=batch_size, replace=take < batch_size)
 
     def node_batch(self, node: int, batch_size: int, rng: np.random.Generator):
-        sel = self._select(self.node_views()[node], node, batch_size, rng)
-        return {k: v[sel] for k, v in self.arrays.items()}
+        with span("draw", nodes=1, steps=1, batch=batch_size):
+            sel = self._select(self.node_views()[node], node, batch_size, rng)
+            return {k: v[sel] for k, v in self.arrays.items()}
 
     @property
     def num_nodes(self) -> int:
@@ -148,23 +151,24 @@ class IDPADataset:
         ``batch["mask"]``).
         """
         m = self.num_nodes
-        views = self.node_views()
-        sizes = self.node_round_batch_sizes(batch_size) if uneven \
-            else np.full(m, batch_size, np.int64)
-        mask = np.zeros((m, local_steps, batch_size), np.float32)
-        sels = []
-        for j in range(m):
-            node = []
-            for s in range(local_steps):
-                sel = self._select(views[j], j, int(sizes[j]), rng)
-                if len(sel) < batch_size:      # pad by cycling; masked out
-                    sel = np.resize(sel, batch_size)
-                node.append(sel)
-                mask[j, s, :sizes[j]] = 1.0
-            sels.append(node)
-        out = {k: np.stack([np.stack([v[sel] for sel in node])
-                            for node in sels])
-               for k, v in self.arrays.items()}
-        if uneven:
-            out["mask"] = mask
-        return out
+        with span("draw", nodes=m, steps=local_steps, batch=batch_size):
+            views = self.node_views()
+            sizes = self.node_round_batch_sizes(batch_size) if uneven \
+                else np.full(m, batch_size, np.int64)
+            mask = np.zeros((m, local_steps, batch_size), np.float32)
+            sels = []
+            for j in range(m):
+                node = []
+                for s in range(local_steps):
+                    sel = self._select(views[j], j, int(sizes[j]), rng)
+                    if len(sel) < batch_size:      # pad by cycling; masked out
+                        sel = np.resize(sel, batch_size)
+                    node.append(sel)
+                    mask[j, s, :sizes[j]] = 1.0
+                sels.append(node)
+            out = {k: np.stack([np.stack([v[sel] for sel in node])
+                                for node in sels])
+                   for k, v in self.arrays.items()}
+            if uneven:
+                out["mask"] = mask
+            return out

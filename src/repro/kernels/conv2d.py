@@ -167,6 +167,7 @@ def _forward(x, w, b, *, padding: str, activation: str, oc_tile: int,
         out_specs=pl.BlockSpec((1, H, W, ct), lambda bi, c: (bi, 0, 0, c)),
         out_shape=jax.ShapeDtypeStruct((B, H, W, Cout), x.dtype),
         interpret=interpret,
+        name="conv_fwd",
     )(xp, w, b.reshape(1, Cout))
 
 
@@ -202,6 +203,7 @@ def _backward_dx(g, w, x_shape, out_dtype, *, padding: str, oc_tile: int,
         out_specs=pl.BlockSpec((1, H, W, ct), lambda bi, c: (bi, 0, 0, c)),
         out_shape=jax.ShapeDtypeStruct((B, H, W, Cin), out_dtype),
         interpret=interpret,
+        name="conv_dx",
     )(gp, wf)
 
 
@@ -272,6 +274,7 @@ def _backward_dw(x, g, w_shape, *, padding: str, oc_tile: int,
         # buffer, so it must not round through the input dtype
         out_shape=jax.ShapeDtypeStruct((kh, kw, Cin, Cout), jnp.float32),
         interpret=interpret,
+        name="conv_dw",
     )(xp, g)
 
 

@@ -156,6 +156,7 @@ def _matmul(a, b, bias=None, *, tn: int, out_dtype, activation="none",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="dense_matmul",
     )(*args)
     return out[:M, :N] if (Mp, Np) != (M, N) else out
 

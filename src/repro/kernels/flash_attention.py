@@ -110,5 +110,6 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,  # 
             pltpu.VMEM((tq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
     return out[:, :, :Sq]

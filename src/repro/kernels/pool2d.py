@@ -89,6 +89,7 @@ def _forward(x, *, window: int, interpret: bool):
         out_specs=pl.BlockSpec((1, Ho, Wo, C), lambda bi: (bi, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, C), x.dtype),
         interpret=interpret,
+        name="pool_fwd",
     )(x)
 
 
@@ -106,6 +107,7 @@ def _backward(x, out, g, *, window: int, interpret: bool):
         out_specs=pl.BlockSpec((1, H, W, C), lambda bi: (bi, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, W, C), x.dtype),
         interpret=interpret,
+        name="pool_bwd",
     )(x, out, g)
 
 

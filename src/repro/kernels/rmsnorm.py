@@ -48,5 +48,6 @@ def rmsnorm_pallas(x, scale, eps: float = 1e-6, row_tile: int = 256,  # reprolin
         out_specs=pl.BlockSpec((rt, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n * rt, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, scale)
     return out[:rows].reshape(orig_shape)

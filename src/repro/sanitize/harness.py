@@ -1,7 +1,8 @@
 """Runtime sanitizer — shardcheck's dynamic half (see docs/LINTING.md).
 
 The static rules (RPL2xx/RPL6xx) prove what the AST can prove; this
-module catches the two failure classes that only exist at run time:
+module catches the two failure classes that only exist at run time, and
+names the host path for the profiler:
 
 * **Hidden transfers.**  ``sanitized()`` arms ``jax.transfer_guard`` so
   that any *implicit* host<->device transfer inside an engine round —
@@ -18,11 +19,19 @@ module catches the two failure classes that only exist at run time:
   assertions — steady-state code paths pin a budget of 0 new compiles,
   the same contract ``ServeEngine.prefill_traces`` enforces per
   function (PR 8 pattern).
+* **Host spans.**  ``span(name)`` is a ``jax.profiler.TraceAnnotation``
+  named ``bpt.<name>``: it lands in the profiler's own trace, on the
+  device planes' clock, and costs one inactive ``TraceMe`` check when no
+  profiler is attached.  Every sanctioned sync is the span
+  ``bpt.sync.<label>``, so the trace and the audit log count the same
+  pulls.  Open spans in host code only: inside a jitted, shard_mapped or
+  Pallas body a span would time the trace, not the run.
 
-Everything is gated on ``REPRO_SANITIZE`` (off by default; the CI tier-1
-matrix runs a ``REPRO_SANITIZE=1`` leg).  With the gate off, ``sanitized``
-is a no-op and ``sanctioned_sync`` still blocks + materializes — callers
-never branch on the env var themselves.
+The guards and the audit log are gated on ``REPRO_SANITIZE`` (off by
+default; the CI tier-1 matrix runs a ``REPRO_SANITIZE=1`` leg).  With the
+gate off, ``sanitized`` is a no-op, ``sync_log`` stays empty and
+``sanctioned_sync`` still blocks + materializes — callers never branch on
+the env var themselves.
 
 Backend honesty note: on the CPU backend device arrays are host-resident,
 so the device-to-host half of the guard never fires there — CPU CI
@@ -40,7 +49,7 @@ import numpy as np
 
 __all__ = [
     "sanitize_enabled", "sanitized", "sanctioned_scope", "sanctioned_sync",
-    "sync_log", "clear_sync_log",
+    "span", "sync_log", "clear_sync_log",
     "install_compile_listener", "compile_counts", "compile_budget",
     "CompileBudgetExceeded",
 ]
@@ -71,14 +80,22 @@ def sanitized(label: str = ""):
         yield
 
 
-# audit trail of sanctioned sync points, most recent last: (label,) tuples
-# are enough for tests to assert "the only syncs were the measured ones"
+def span(name: str, **args):
+    """The host span ``bpt.<name>`` in the profiler's trace, with ``args``
+    as its event stats (ints, floats or strings)."""
+    return jax.profiler.TraceAnnotation(f"bpt.{name}", **args)
+
+
+# audit trail of sanctioned sync points while the gate is on, most recent
+# last: labels are enough for tests to assert "the only syncs were the
+# measured ones"
 _sync_log: list = []
 _sync_lock = threading.Lock()
 
 
 def sync_log() -> list:
-    """Labels of every sanctioned sync since the last clear (copy)."""
+    """Labels of every sanctioned sync made with the gate on since the
+    last clear (copy)."""
     with _sync_lock:
         return list(_sync_log)
 
@@ -90,14 +107,16 @@ def clear_sync_log() -> None:
 
 @contextlib.contextmanager
 def sanctioned_scope(label: str):
-    """The audited escape hatch: transfers are allowed inside, and the
-    scope is recorded in ``sync_log()``.  Use it where a host sync IS
-    the semantics — measured-wall boundaries (``MeasuredTimer``),
-    accuracy evals whose scalar feeds Eq. 7/10 weighting."""
-    with jax.transfer_guard("allow"):
+    """The audited escape hatch: transfers are allowed inside, the scope
+    is the span ``bpt.sync.<label>``, and with the gate on it is recorded
+    in ``sync_log()``.  Use it where a host sync IS the semantics —
+    measured-wall boundaries (``MeasuredTimer``), accuracy evals whose
+    scalar feeds Eq. 7/10 weighting."""
+    with span(f"sync.{label}"), jax.transfer_guard("allow"):
         yield
-    with _sync_lock:
-        _sync_log.append(label)
+    if sanitize_enabled():
+        with _sync_lock:
+            _sync_log.append(label)
 
 
 def sanctioned_sync(x, label: str = "sync"):

@@ -154,6 +154,38 @@ class TestTraceRules:
             """, only=["RPL201", "RPL202"])
         assert out == []
 
+    def test_host_span_in_trace_is_flagged(self):
+        out = lint("""
+            import jax
+            from repro.sanitize import span
+
+            def step(x):
+                with span("dispatch"):
+                    return x + 1
+
+            def round_fn(x):
+                with jax.profiler.TraceAnnotation("bpt.round"):
+                    return x * 2
+
+            run = jax.jit(step)
+            run2 = jax.jit(round_fn)
+            """, only=["RPL202"])
+        assert rules_of(out) == ["RPL202", "RPL202"]
+        assert "host span" in out[0].message
+
+    def test_host_span_around_a_dispatch_is_clean(self):
+        out = lint("""
+            import jax
+            from repro.sanitize import span
+
+            step = jax.jit(lambda x: x + 1)
+
+            def run_round(x):
+                with span("dispatch"):
+                    return step(x)
+            """, only=["RPL202"])
+        assert out == []
+
     def test_jax_random_is_not_nondet(self):
         out = lint("""
             import jax

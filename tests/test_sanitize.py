@@ -100,6 +100,14 @@ class TestTransferGuard:
         out = sanctioned_sync(jnp.ones(3), "plain")
         assert isinstance(out, np.ndarray)
 
+    def test_gate_off_keeps_the_audit_log_empty(self, monkeypatch):
+        monkeypatch.setenv("REPRO_SANITIZE", "0")
+        clear_sync_log()
+        sanctioned_sync(jnp.ones(3), "round.losses")
+        with sanctioned_scope("eval"):
+            pass
+        assert sync_log() == []
+
 
 # ----------------------------------------------------------------------
 # compile budgets

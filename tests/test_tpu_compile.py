@@ -5,7 +5,9 @@ step at Table-2 case7 widths (10 conv layers of 12 3x3 filters on
 32x32x3 images, 7 FC layers of 2000 neurons, 10 classes) for one chip of
 a described ``v5e:2x2`` topology.  The TPU compiler refuses here what
 interpret mode cannot see: a block the lowering does not accept, a grid
-cell that overflows VMEM.  A pass is a compile, not a chip run.
+cell that overflows VMEM.  A pass is a compile, not a chip run.  Each
+kernel carries its stable ``name`` into the lowered program, where the
+profiler's trace finds it.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and a worker that is not
@@ -73,8 +75,12 @@ def compiled_kernels(monkeypatch):
     ops.clear_fallback_log()
 
 
-def _compile(fn, *args):
-    compiled = jax.jit(fn).lower(*args).compile()
+def _compile(fn, *args, names=()):
+    lowered = jax.jit(fn).lower(*args)
+    text = lowered.as_text()
+    for name in names:
+        assert f'kernel_name = "{name}"' in text, f"no kernel named {name}"
+    compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text(), "no Pallas kernel"
     assert ops.fallback_events() == {}
     return compiled
@@ -98,7 +104,10 @@ def test_conv2d_compiles(one_chip, compiled_kernels, size, cin, cout,
     k = CASE.filter_size
     args = (_spec(one_chip, (BATCH, size, size, cin)),
             _spec(one_chip, (k, k, cin, cout)), _spec(one_chip, (cout,)))
-    _compile(f if direction == "forward" else _grad(f), *args)
+    if direction == "forward":
+        _compile(f, *args, names=("conv_fwd",))
+    else:
+        _compile(_grad(f), *args, names=("conv_fwd", "conv_dx", "conv_dw"))
 
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])
@@ -109,8 +118,11 @@ def test_max_pool2d_compiles(one_chip, compiled_kernels, size, channels,
         return ops.max_pool2d(x, impl="pallas")
 
     g = jax.grad(lambda x: jnp.sum(f(x) ** 2))
-    _compile(f if direction == "forward" else g,
-             _spec(one_chip, (BATCH, size, size, channels)))
+    x = _spec(one_chip, (BATCH, size, size, channels))
+    if direction == "forward":
+        _compile(f, x, names=("pool_fwd",))
+    else:
+        _compile(g, x, names=("pool_fwd", "pool_bwd"))
 
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])
@@ -122,7 +134,8 @@ def test_dense_compiles(one_chip, compiled_kernels, d_in, d_out,
 
     args = (_spec(one_chip, (BATCH, d_in)), _spec(one_chip, (d_in, d_out)),
             _spec(one_chip, (d_out,)))
-    _compile(f if direction == "forward" else _grad(f), *args)
+    _compile(f if direction == "forward" else _grad(f), *args,
+             names=("dense_matmul",))
 
 
 def test_vmapped_node_step_compiles(one_chip, compiled_kernels):

@@ -15,7 +15,9 @@ reference transitively inside the module — and flag:
   ``jax.device_get``, ``.item()``, ``np.asarray``/``np.array`` calls.
 * RPL202 ``nondet-in-trace``: ``time.*`` calls, stdlib ``random.*`` and
   ``np.random.*`` calls (``jax.random`` is keyed and deterministic and
-  does NOT flag), and argless ``datetime.now()``.
+  does NOT flag), argless ``datetime.now()``, and host spans
+  (``repro.sanitize.span``, ``TraceAnnotation``), which would time the
+  trace, not the run.
 
 ``TIMER_ALLOWLIST`` names the engine timer scopes that are *supposed*
 to measure walls (the serving ``MeasuredTimer`` — the serving twin of
@@ -165,8 +167,15 @@ def _host_sync_reason(call: ast.Call) -> Optional[str]:
     return None
 
 
+# host spans: inside traced code they record trace time, not run time
+HOST_SPANS = frozenset({"span", "TraceAnnotation", "StepTraceAnnotation"})
+
+
 def _nondet_reason(call: ast.Call) -> Optional[str]:
     fn = call.func
+    if terminal_name(fn) in HOST_SPANS:
+        return (f"`{terminal_name(fn)}` opens a host span, which would "
+                "time the trace, not the run")
     if not isinstance(fn, ast.Attribute):
         return None
     base = fn.value
@@ -244,8 +253,8 @@ class NondetInTraceRule(_TraceHygieneRule):
     id = "RPL202"
     name = "nondet-in-trace"
     description = ("time.* / random.* / np.random.* / argless datetime.now "
-                   "must not run inside trace-reachable functions (timer "
-                   "scopes in TIMER_ALLOWLIST exempt)")
+                   "/ host spans must not run inside trace-reachable "
+                   "functions (timer scopes in TIMER_ALLOWLIST exempt)")
 
     def _reason(self, call):
         return _nondet_reason(call)
