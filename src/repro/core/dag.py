@@ -241,9 +241,10 @@ def fc_grid_tasks(dag: TaskDAG, d_out: int, block: int,
     This is the paper's §4.1.2 G_FC granularity expressed at the grid the
     Pallas dense kernel actually runs — ceil(d_out/block) cells, each one
     ``(B, Din) x (Din, block)`` matmul task.  A ``LANE``-multiple block
-    need not divide ``d_out``: the kernel pads the layer up to whole
-    blocks, so every task costs ``block * cost_per_neuron`` (padded lanes
-    compute too).  All tasks are mutually independent.
+    need not divide ``d_out``: the kernel's last block is ragged and
+    computes its whole width, so every task costs
+    ``block * cost_per_neuron`` (lanes past ``d_out`` compute too, their
+    results are never stored).  All tasks are mutually independent.
     """
     if block <= 0 or (d_out % block and block % LANE):
         raise ValueError(

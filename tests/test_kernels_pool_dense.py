@@ -116,8 +116,17 @@ def _dense_grid():
         (4, 8, "relu", True, (2, 16, 8)),    # block == Dout
         (5, 2, "none", False, (1, 6, 10)),   # no bias, odd dims
         (6, 5, "relu", False, (3, 7, 10)),   # Din not divisible by block
-        (7, 128, "relu", True, (5, 40, 200)),    # lane block: Dout pads
-        (8, 128, "none", True, (4, 300, 130)),   # dx reuses it: Din pads
+        (7, 128, "relu", True, (5, 40, 200)),    # lane block: Dout ragged
+        (8, 128, "none", True, (4, 300, 130)),   # dx reuses it: Din ragged
+        # widths past MAX_TILE that are no lane multiples: whole-K blocks,
+        # ragged row/column edges in all three passes
+        (9, 256, "relu", True, (8, 600, 520)),
+        (10, 128, "none", False, (3, 700, 530)),
+        # K too wide for one whole-K block: the masked ragged K tail, in
+        # the forward and dx (K = 1400 features) ...
+        (11, 512, "relu", True, (256, 1400, 1400)),
+        # ... and in dw (K = 1400 samples, the rows of x^T)
+        (12, 512, "none", False, (1400, 256, 768)),
     ]
 
 
@@ -295,11 +304,11 @@ class TestFallbackContract:
 
     def test_explicit_pallas_wide_dense_is_tiled(self):
         """A matmul far wider than one VMEM cell is served by the kernel
-        itself — rows and the contraction tile and pad — never by the
-        ref: no raise, no recorded fallback, the ref's answer."""
+        itself — rows and the contraction tile, with ragged edges — never
+        by the ref: no raise, no recorded fallback, the ref's answer."""
         k1, k2, k3 = jax.random.split(jax.random.PRNGKey(29), 3)
         x = rand(k1, (300, 600))          # rows > 256, K > 512: both tile
-        w = rand(k2, (600, 200))          # Dout pads to the 128 block
+        w = rand(k2, (600, 200))          # Dout: a ragged 128 block
         b = rand(k3, (200,))
         got = ops.dense(x, w, b, activation="relu", impl="pallas")
         want = ops.dense(x, w, b, activation="relu", impl="ref")
