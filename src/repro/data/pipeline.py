@@ -166,9 +166,10 @@ class IDPADataset:
                     node.append(sel)
                     mask[j, s, :sizes[j]] = 1.0
                 sels.append(node)
-            out = {k: np.stack([np.stack([v[sel] for sel in node])
-                                for node in sels])
-                   for k, v in self.arrays.items()}
+            # one gather straight into the (m, local_steps, B, ...) batch:
+            # the round's images are copied once, not once per stacking
+            idx = np.asarray(sels)
+            out = {k: v[idx] for k, v in self.arrays.items()}
             if uneven:
                 out["mask"] = mask
             return out

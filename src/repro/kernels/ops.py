@@ -7,21 +7,23 @@ Implementation selection:
     compiled on TPU).  ``conv2d``, ``max_pool2d`` and ``dense`` are fully
     differentiable through their ``custom_vjp`` backward kernels, so the
     whole CNN forward+backward (conv Eq. 13, pooling Eq. 15/18, FC
-    Eq. 19-21) is a real Pallas training path.
+    Eq. 19-21) is a real Pallas training path.  ``depthwise_conv2d``
+    (ConvNeXt's 7x7 spatial filter) differentiates the same way.
 
 Kernel entry points take ``interpret=None`` and resolve it through
 ``_interpret()`` here — the single switch that decides interpret-vs-compiled
 — so no call site can silently ship interpret-mode kernels to a TPU.
 
 **Fallback contract**: when the pallas impl cannot serve a call (a
-strided conv, overlapping pooling) the fallback to the jnp ref is never
-silent.  An explicit ``impl="pallas"``
-argument raises ``NotImplementedError``; an environment/default-selected
-pallas impl warns once per (op, reason) with ``KernelFallbackWarning`` and
-records the event in ``fallback_events()`` — tests assert the log stays
-empty on the paths that must be all-Pallas.  Dispatch happens in Python,
-so events are recorded at *trace* time: one entry per traced call site,
-not per compiled execution (re-running an already-jitted function records
+strided conv, a strided or VALID depthwise conv, overlapping pooling)
+the fallback to the jnp ref is never silent.  An explicit
+``impl="pallas"`` argument raises ``NotImplementedError``; an
+environment/default-selected pallas impl warns once per (op, reason)
+with ``KernelFallbackWarning`` and records the event in
+``fallback_events()`` — tests assert the log stays empty on the paths
+that must be all-Pallas.  Dispatch happens in Python, so events are
+recorded at *trace* time: one entry per traced call site, not per
+compiled execution (re-running an already-jitted function records
 nothing new — assert on the log in eager code or around fresh traces).
 
 Default task granularities come from ``core.dag``'s Alg. 4.2 cost model —
@@ -48,13 +50,14 @@ import jax
 from . import ref
 from .conv2d import conv2d_pallas
 from .dense import dense_pallas
+from .dwconv2d import dwconv2d_pallas
 from .flash_attention import flash_attention_pallas
 from .pool2d import max_pool2d_pallas
 from .rmsnorm import rmsnorm_pallas
 
-__all__ = ["conv2d", "max_pool2d", "dense", "flash_attention", "rmsnorm",
-           "default_impl", "KernelFallbackWarning", "fallback_events",
-           "clear_fallback_log"]
+__all__ = ["conv2d", "depthwise_conv2d", "max_pool2d", "dense",
+           "flash_attention", "rmsnorm", "default_impl",
+           "KernelFallbackWarning", "fallback_events", "clear_fallback_log"]
 
 
 def default_impl() -> str:
@@ -152,6 +155,28 @@ def conv2d(x, w, b=None, padding: str = "SAME", stride: int = 1,
         out = jax.nn.relu(out)
     elif activation != "none":
         raise ValueError(activation)
+    return out
+
+
+def depthwise_conv2d(x, w, b=None, padding: str = "SAME", stride: int = 1,
+                     impl: str = ""):
+    """Depthwise conv + optional fused bias: (B,H,W,C) x (kh,kw,C).
+
+    The Pallas path (SAME, stride 1 — ConvNeXt's 7x7 filter) is
+    differentiable via ``custom_vjp``; any other stride or padding takes
+    the explicit-fallback contract.
+    """
+    explicit = impl == "pallas"
+    impl = impl or default_impl()
+    if impl == "pallas":
+        if stride == 1 and padding == "SAME":
+            return dwconv2d_pallas(x, w, b, interpret=_interpret())
+        _fallback("depthwise_conv2d",
+                  f"padding={padding} stride={stride} is unsupported "
+                  "(SAME, stride-1 kernel only)", explicit)
+    out = ref.depthwise_conv2d_ref(x, w, padding=padding, stride=stride)
+    if b is not None:
+        out = out + b.astype(out.dtype)
     return out
 
 

@@ -9,8 +9,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["conv2d_ref", "max_pool2d_ref", "dense_ref", "attention_ref",
-           "rmsnorm_ref"]
+__all__ = ["conv2d_ref", "depthwise_conv2d_ref", "max_pool2d_ref",
+           "dense_ref", "attention_ref", "rmsnorm_ref"]
 
 
 def conv2d_ref(x, w, padding: str = "SAME", stride: int = 1):
@@ -38,6 +38,16 @@ def conv2d_ref(x, w, padding: str = "SAME", stride: int = 1):
     wmat = w.transpose(0, 1, 2, 3).reshape(kh * kw * Cin, Cout)
     out = cols.reshape(B, Ho, Wo, kh * kw * Cin) @ wmat.astype(x.dtype)
     return out
+
+
+def depthwise_conv2d_ref(x, w, padding: str = "SAME", stride: int = 1):
+    """Depthwise convolution, NHWC x (kh, kw, C) -> NHWC: each channel
+    filtered by its own taps (a grouped conv, one group per channel).
+    Differentiable."""
+    return jax.lax.conv_general_dilated(
+        x, w[:, :, None, :].astype(x.dtype), (stride, stride), padding,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=x.shape[-1])
 
 
 def max_pool2d_ref(x, window: int = 2, stride: int = 2):

@@ -7,9 +7,10 @@ import jax.numpy as jnp
 from repro.core.shardlib import constrain
 
 __all__ = [
-    "rms_norm", "init_dense", "dense", "init_mlp", "mlp",
-    "rope_frequencies", "apply_rope", "init_embedding", "embed",
-    "softcap", "init_rms_norm", "init_conv2d", "conv2d", "init_fc", "fc",
+    "rms_norm", "init_layer_norm", "layer_norm", "init_dense", "dense",
+    "init_mlp", "mlp", "rope_frequencies", "apply_rope", "init_embedding",
+    "embed", "softcap", "init_rms_norm", "init_conv2d", "conv2d", "init_fc",
+    "fc",
 ]
 
 
@@ -23,6 +24,21 @@ def rms_norm(params, x, eps: float = 1e-6):
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     x = x * jax.lax.rsqrt(var + eps)
     return (x * params["scale"].astype(jnp.float32)).astype(dt)
+
+
+def init_layer_norm(d: int, dtype=jnp.float32):
+    return {"scale": jnp.ones((d,), dtype), "bias": jnp.zeros((d,), dtype)}
+
+
+def layer_norm(params, x, eps: float = 1e-6):
+    """LayerNorm over the last axis (biased variance), in float32."""
+    dt = x.dtype
+    x = x.astype(jnp.float32)
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+    x = (x - mean) * jax.lax.rsqrt(var + eps)
+    return (x * params["scale"].astype(jnp.float32)
+            + params["bias"].astype(jnp.float32)).astype(dt)
 
 
 def softcap(x, cap: float):

@@ -1,14 +1,15 @@
-"""Ahead-of-time compiles of the CNN main path for a TPU v5e, no chip needed.
+"""Ahead-of-time compiles of the CNN main paths for a TPU v5e, no chip needed.
 
 Each test compiles — never runs — the Pallas kernels or the trainer's node
 step at Table-2 case7 widths (10 conv layers of 12 3x3 filters on
-32x32x3 images, 7 FC layers of 2000 neurons, 10 classes), and some at
-case4's (8 conv x 8 filters, 5 FC x 1500), for one chip of a described
-``v5e:2x2`` topology.  The TPU compiler refuses here what
-interpret mode cannot see: a block the lowering does not accept, a grid
-cell that overflows VMEM.  A pass is a compile, not a chip run.  Each
-kernel carries its stable ``name`` into the lowered program, where the
-profiler's trace finds it.
+32x32x3 images, 7 FC layers of 2000 neurons, 10 classes), some at
+case4's (8 conv x 8 filters, 5 FC x 1500), and the depthwise kernels and
+node step of ConvNeXt-T at its published widths (16 images of 224 px a
+node), for one chip of a described ``v5e:2x2`` topology.  The TPU
+compiler refuses here what interpret mode cannot see: a block the
+lowering does not accept, a grid cell that overflows VMEM.  A pass is a
+compile, not a chip run.  Each kernel carries its stable ``name`` into
+the lowered program, where the profiler's trace finds it.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and a worker that is not
@@ -26,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import MAX_TILE, dense, ops
 from repro.models.cnn import (_conv_shapes, cnn_accuracy, cnn_loss, init_cnn,
                               make_case)
+from repro.models.convnext import CONVNEXT_T, convnext_loss, init_convnext
 
 CASE = make_case("case7")
 CASE4 = make_case("case4")
@@ -242,3 +244,45 @@ def test_case_shapes_cover_case7():
     # 32 -> 16 -> 8 -> 4 px: three pooled conv widths, then 4 px convs
     assert [c[0] for c in CONVS] == [32, 16, 8, 4]
     assert DENSES == [(192, 2000), (2000, 10), (2000, 2000)]
+
+
+# ConvNeXt-T's depthwise stages: (map size, channels), 16 images a node
+DWCONVS = [(56, 96), (28, 192), (14, 384), (7, 768)]
+CONVNEXT_BATCH = 16
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("size,channels", DWCONVS)
+def test_depthwise_conv_compiles(one_chip, compiled_kernels, size, channels,
+                                 direction):
+    """Each cell holds one padded 7x7 plane of its image in VMEM: the
+    widest, stage 1's 62x62x96, must fit the default scoped limit."""
+    def f(x, w, b):
+        return ops.depthwise_conv2d(x, w, b, impl="pallas")
+
+    args = (_spec(one_chip, (CONVNEXT_BATCH, size, size, channels)),
+            _spec(one_chip, (7, 7, channels)), _spec(one_chip, (channels,)))
+    if direction == "forward":
+        _compile(f, *args, names=("dwconv_fwd",))
+    else:        # the gradient of a sum needs no forward value
+        _compile(_grad(f), *args, names=("dwconv_dx", "dwconv_dw"))
+
+
+def test_vmapped_convnext_node_step_compiles(one_chip, compiled_kernels):
+    """The fused SGWU round's core for ConvNeXt-T at 224 px: m nodes'
+    value_and_grad in one vmapped program, the depthwise convs, the
+    patchify convs and the pointwise MLPs all compiled Pallas kernels,
+    and the activations of 4 x 16 images within one v5e's HBM."""
+    params = jax.eval_shape(lambda: init_convnext(jax.random.PRNGKey(0)))
+    stacked = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, (NODES,) + a.shape, a.dtype), params)
+    size = CONVNEXT_T.image_size
+    batch = {"images": _spec(one_chip, (NODES, CONVNEXT_BATCH, size, size,
+                                        CONVNEXT_T.in_channels)),
+             "labels": _spec(one_chip, (NODES, CONVNEXT_BATCH), jnp.int32)}
+    step = jax.vmap(jax.value_and_grad(lambda p, b: convnext_loss(p, b)))
+    compiled = _compile(step, stacked, batch,
+                        names=("dwconv_fwd", "dwconv_dx", "dwconv_dw",
+                               "dense_matmul"))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 10**9
