@@ -5,8 +5,10 @@ Run from the repository root, as one process that starts no others:
     python chip_smoke.py             # one chip
     python chip_smoke.py --chips 4   # the four-chip outer layer only
 
-With no option it checks the compiled Pallas path against the jnp
-reference on the chip (one case7 forward and gradient), then trains case7
+With no option it checks the training set's draw from the chip against the
+host draw (``check_resident_gather``), and the compiled Pallas path against
+the jnp reference on the chip (one case7 forward and gradient), then trains
+case7
 (10 conv layers of 12 filters, 7 FC layers of 2000 neurons, 32x32x3
 images) with m=4 nodes, a per-node batch of 64 and 2 local steps: SGWU
 rounds on ``VmapEngine`` — again under the jnp reference, whose losses
@@ -131,6 +133,47 @@ def check_against_ref(s: Smoke, cfg, params, xs, ys) -> None:
     _check(f"gradient (worst leaf {worst[1]})", worst[0], REF_RTOL)
 
 
+# the benchmark cells' sets and rounds: (images, size, nodes, steps, batch)
+GATHER_SHAPES = ((4096, 32, 4, 2, 64), (1024, 224, 4, 2, 16))
+
+
+def check_resident_gather(seed: int = 0) -> None:
+    """At each of ``GATHER_SHAPES``, a round drawn from the set kept on the
+    chip (``IDPADataset.to_device``) equals the host draw of the same
+    indices bit for bit, with and without ``uneven``, and leaves the RNG
+    where the host draw leaves it."""
+    import jax
+    from repro.data.pipeline import IDPADataset
+    gen = np.random.default_rng(seed)
+    for n, size, m, h, b in GATHER_SHAPES:
+        ds = IDPADataset(
+            {"images": gen.standard_normal((n, size, size, 3), np.float32),
+             "labels": gen.integers(0, 1000, n, dtype=np.int32)},
+            num_nodes=m, batches=3, frequencies=1.0 / (1.0 + 0.5 *
+                                                       np.arange(m)),
+            idpa_mode="balanced")
+        rows = ds.to_device()
+        for uneven in (False, True):
+            host_rng = np.random.default_rng(seed + n)
+            dev_rng = np.random.default_rng(seed + n)
+            want = ds.stacked_round_batches(b, h, host_rng, uneven=uneven)
+            got = jax.device_get(ds.stacked_round_batches(
+                b, h, dev_rng, uneven=uneven, resident=rows))
+            if sorted(got) != sorted(want) or any(
+                    got[k].dtype != want[k].dtype or
+                    not np.array_equal(got[k], want[k]) for k in want):
+                raise AssertionError(f"resident draw of {n} x {size} px "
+                                     f"(uneven={uneven}) differs")
+            if dev_rng.bit_generator.state != host_rng.bit_generator.state:
+                raise AssertionError("resident draw consumed the RNG "
+                                     "differently")
+        temp = ds.gather_temp_bytes((m, h, b), jax.local_devices()[0])
+        _log(f"resident draw of {n} x {size} px equals the host draw; "
+             f"set {sum(x.on_device_size_in_bytes() for x in rows.values())}"
+             f" bytes on the chip ({ds.nbytes} on the host), gather "
+             f"temporaries {temp} bytes")
+
+
 def make_trainer(s: Smoke, engine: str, cfg, params, data, *, rounds: int,
                  eval_fn=None, batches: int = 3, **overrides):
     """A BPTTrainer on the named engine, as the example drivers build it."""
@@ -191,6 +234,7 @@ def one_chip(s: Smoke) -> None:
     _log(f"{s.case}: {cfg.conv_layers} conv x {cfg.filters} filters, "
          f"{cfg.fc_layers} fc x {cfg.fc_neurons}, {n} parameters")
     xs, ys, xe, ye = _data(s)
+    check_resident_gather(s.seed)
     check_against_ref(s, cfg, params, xs, ys)
 
     eval_batch = jax.device_put({"images": jnp.asarray(xe),

@@ -55,10 +55,32 @@ __all__ = [
 
 def place_batches(tree, sharding=None):
     """One explicit placement of a round's host batches, as the span
-    ``bpt.place`` (its ``bytes``: the host leaves' total size)."""
-    nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(tree))
+    ``bpt.place`` (its ``bytes``: the host leaves' total size).  A leaf
+    already on the device (a resident set's draw) counts no bytes, and
+    with no ``sharding`` it is not moved."""
+    nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(tree)
+                 if not isinstance(x, jax.Array))
     with span("place", bytes=int(nbytes)):
         return jax.device_put(tree, sharding)
+
+
+RESIDENT_SET_SHARE = 0.25
+
+
+def set_fits(dataset, round_shape: tuple, device) -> bool:
+    """Whether ``dataset``'s training set may live on ``device`` for
+    rounds that draw ``round_shape`` indices: the set plus the temporaries
+    of its gather (``IDPADataset.gather_temp_bytes``, from the compiled
+    gather) at most ``RESIDENT_SET_SHARE`` of the memory the device
+    reports.  They share the device with a round's weights, optimizer
+    state and temporaries (ConvNeXt-T's fused round holds ~10.3 GB of a
+    v5e's 16 GB), so a quarter leaves the round its room.  A device that
+    reports no limit (the CPU backend) takes the set."""
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if limit is None:
+        return True
+    need = dataset.nbytes + dataset.gather_temp_bytes(round_shape, device)
+    return need <= RESIDENT_SET_SHARE * limit
 
 
 # ----------------------------------------------------------------------
@@ -354,6 +376,7 @@ class _StackedState:
     batch_sharding: Any
     clock: float = 0.0
     sync_wait: float = 0.0
+    resident: Any = None       # the training set on the device, or None
 
 
 class _StackedSGWUEngine(OuterEngine):
@@ -410,7 +433,7 @@ class _StackedSGWUEngine(OuterEngine):
             active=alive if faults else None)
         batches = t.dataset.stacked_round_batches(
             t.batch_size, t.tc.local_steps, t.rng,
-            uneven=t.tc.uneven_batches)
+            uneven=t.tc.uneven_batches, resident=st.resident)
         # explicit placement even on the fused single-device path
         # (batch_sharding None -> default device): the round dispatch
         # below must never upload the host batches implicitly
@@ -459,9 +482,22 @@ class VmapEngine(_StackedSGWUEngine):
     Node-stacked params/opt-states flow ``pull_all_stacked`` ->
     ``_fused_round`` (vmap over nodes, scan over local steps, stacked
     buffers donated) -> ``push_sgwu_stacked`` (jitted Eq. 7 merge on the
-    stack, donated).
+    stack, donated).  Where the training set fits the device
+    (``set_fits``), it is placed there once and each round's batch is
+    gathered on the device.
     """
     backend = "vmap"
+
+    def setup(self, rounds):
+        st = super().setup(rounds)
+        ds = self.t.dataset
+        shape = (ds.num_nodes, self.t.tc.local_steps, self.t.batch_size)
+        # placed on the default device uncommitted, as place_batches puts
+        # a host draw: a committed batch would commit the round's outputs
+        # and so recompile the round once
+        if set_fits(ds, shape, jax.local_devices()[0]):
+            st.resident = ds.to_device()
+        return st
 
     def _build(self):
         t = self.t

@@ -8,8 +8,10 @@ server" is the input pipeline.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.core.idpa import IDPAPartitioner, UDPAPartitioner
@@ -35,6 +37,13 @@ def pack_sequences(corpus: np.ndarray, seq_len: int) -> np.ndarray:
 def host_batch(rows: np.ndarray):
     """(B, S+1) rows -> {'tokens': (B,S), 'labels': (B,S)}."""
     return {"tokens": rows[:, :-1], "labels": rows[:, 1:]}
+
+
+@partial(jax.jit, static_argnums=2)
+def _gather_rows(rows: dict, idx, shapes: tuple):
+    """Each leaf's rows at ``idx``, shaped ``idx.shape + shapes[k]``
+    (``shapes``: the ``(name, sample shape)`` pairs of the leaves)."""
+    return {k: rows[k][idx].reshape(idx.shape + shape) for k, shape in shapes}
 
 
 class IDPADataset:
@@ -67,6 +76,45 @@ class IDPADataset:
     @property
     def totals(self) -> np.ndarray:
         return self.part.totals
+
+    @property
+    def nbytes(self) -> int:
+        """The host training arrays' total size."""
+        return sum(v.nbytes for v in self.arrays.values())
+
+    @property
+    def _shapes(self) -> tuple:
+        return tuple((k, v.shape[1:]) for k, v in self.arrays.items())
+
+    def _rows(self) -> dict:
+        """The training arrays with each sample one row: a leaf of more
+        than two dimensions is viewed as ``(n, features)``.  On the TPU a
+        gather of a ``(n, H, W, 3)`` set relays the whole set out on
+        every call; one of rows reads only the rows it takes, where XLA
+        need not slice a long row (``gather_temp_bytes`` says when)."""
+        return {k: v.reshape(len(v), -1) if v.ndim > 2 else v
+                for k, v in self.arrays.items()}
+
+    def to_device(self) -> dict:
+        """The training arrays as rows (``_rows``) on the default device,
+        placed once as the span ``bpt.place``; the host arrays stay as
+        they are.  Given to ``stacked_round_batches`` as ``resident``, the
+        round's batch is gathered there.  The allocation never moves a
+        sample between nodes (paper §3.3.1), so the stripes stay where
+        the nodes compute."""
+        with span("place", bytes=self.nbytes):
+            return jax.device_put(self._rows())
+
+    def gather_temp_bytes(self, round_shape: tuple, device) -> int:
+        """The device temporaries of the resident gather of a round's
+        ``round_shape`` indices on ``device``, from its compiled memory
+        analysis (nothing is placed or run)."""
+        on = jax.sharding.SingleDeviceSharding(device)
+        rows = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=on)
+                for k, v in self._rows().items()}
+        idx = jax.ShapeDtypeStruct(round_shape, np.int32, sharding=on)
+        compiled = _gather_rows.lower(rows, idx, self._shapes).compile()
+        return int(compiled.memory_analysis().temp_size_in_bytes)
 
     def report_durations(self, durations, active=None) -> bool:
         """Feed measured per-node durations; returns True if re-allocated.
@@ -110,7 +158,7 @@ class IDPADataset:
         return rng.choice(view, size=batch_size, replace=take < batch_size)
 
     def node_batch(self, node: int, batch_size: int, rng: np.random.Generator):
-        with span("draw", nodes=1, steps=1, batch=batch_size):
+        with span("draw", nodes=1, steps=1, batch=batch_size, resident=0):
             sel = self._select(self.node_views()[node], node, batch_size, rng)
             return {k: v[sel] for k, v in self.arrays.items()}
 
@@ -132,7 +180,8 @@ class IDPADataset:
 
     def stacked_round_batches(self, batch_size: int, local_steps: int,
                               rng: np.random.Generator, *,
-                              uneven: bool = False):
+                              uneven: bool = False,
+                              resident: Optional[dict] = None):
         """One SGWU round's data for ALL nodes: ``(m, local_steps, B, ...)``.
 
         Draws node-by-node, step-by-step — the exact RNG consumption
@@ -149,9 +198,16 @@ class IDPADataset:
         static-shape realization of IDPA's per-node loads that the
         fused/device-sharded round needs (the loss must honour
         ``batch["mask"]``).
+
+        Given ``resident``, the set's copy on the device (``to_device``),
+        the same indices (and mask) are put on the device explicitly and
+        every leaf is gathered there in one call: the batch is
+        bit-identical to the host draw and already on the device (the
+        span's ``resident`` is 1).
         """
         m = self.num_nodes
-        with span("draw", nodes=m, steps=local_steps, batch=batch_size):
+        with span("draw", nodes=m, steps=local_steps, batch=batch_size,
+                  resident=int(resident is not None)):
             views = self.node_views()
             sizes = self.node_round_batch_sizes(batch_size) if uneven \
                 else np.full(m, batch_size, np.int64)
@@ -169,7 +225,19 @@ class IDPADataset:
             # one gather straight into the (m, local_steps, B, ...) batch:
             # the round's images are copied once, not once per stacking
             idx = np.asarray(sels)
-            out = {k: v[idx] for k, v in self.arrays.items()}
+            if resident is not None:
+                # the stripes' indices lie in [0, n); past them the host
+                # gather raises where the device one would clamp
+                if idx.min() < 0 or idx.max() >= self.n:
+                    raise IndexError(f"index out of [0, {self.n})")
+                # explicit uploads: the round body runs under the transfer
+                # guard (REPRO_SANITIZE=1)
+                out = _gather_rows(resident, jax.device_put(idx),
+                                   self._shapes)
+                if uneven:
+                    mask = jax.device_put(mask)
+            else:
+                out = {k: v[idx] for k, v in self.arrays.items()}
             if uneven:
                 out["mask"] = mask
             return out

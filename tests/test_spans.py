@@ -1,7 +1,8 @@
 """The program's host spans (``repro.sanitize.span``) in a profiler trace.
 
-Two SGWU rounds on ``VmapEngine`` and two AGWU pushes on ``HeapEngine``
-run under ``jax.profiler.trace`` at a tiny CNN size; the trace file is read
+Two SGWU rounds on ``VmapEngine`` (on the training set kept on the device,
+and again on the host draw) and two AGWU pushes on ``HeapEngine`` run under
+``jax.profiler.trace`` at a tiny CNN size; the trace file is read
 back with ``ProfileData.from_file``.  Each event is one ``bpt.round`` span
 holding every host step of the merge, every sanctioned sync is one
 ``bpt.sync.<label>`` span (as many as the sanitizer's audit log counts),
@@ -15,6 +16,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
+import repro.core.engine as engine
 from repro.core.bpt_trainer import BPTTrainer
 from repro.core.types import TrainConfig
 from repro.data.pipeline import IDPADataset
@@ -45,17 +47,21 @@ def _trainer(strategy):
                                                              cfg)))
 
 
-def _drive(strategy):
+def _drive(strategy, resident=True):
     """EVENTS events of a fresh trainer: (losses, IDPA allocation batch
-    after each event)."""
+    after each event).  ``resident=False`` keeps an SGWU set on the host
+    (the CPU reports no memory limit, so ``set_fits`` would take it)."""
     t = _trainer(strategy)
     losses, allocs = [], []
-    gen = t.run(10)
-    for _ in range(EVENTS):
-        ev = next(gen)
-        losses.append(np.asarray(ev.node_losses))
-        allocs.append(t.dataset.part.current_batch)
-    gen.close()
+    with pytest.MonkeyPatch.context() as mp:
+        if not resident:
+            mp.setattr(engine, "set_fits", lambda *a: False)
+        gen = t.run(10)
+        for _ in range(EVENTS):
+            ev = next(gen)
+            losses.append(np.asarray(ev.node_losses))
+            allocs.append(t.dataset.part.current_batch)
+        gen.close()
     return losses, allocs, t.last_plan.backend
 
 
@@ -74,20 +80,25 @@ def _spans(path):
     return sorted(out, key=lambda s: s[1])
 
 
-@pytest.fixture(scope="module", params=["sgwu", "agwu"])
+@pytest.fixture(scope="module", params=[("sgwu", True), ("sgwu", False),
+                                        ("agwu", False)],
+                ids=["sgwu", "sgwu-host", "agwu"])
 def traced(request, tmp_path_factory):
-    """One traced run of each strategy, with the sanitizer's gate on so
-    that its audit log counts the same syncs as the trace."""
-    path = str(tmp_path_factory.mktemp(f"trace_{request.param}"))
+    """One traced run of each strategy (SGWU on a resident set and on the
+    host draw), with the sanitizer's gate on so that its audit log counts
+    the same syncs as the trace."""
+    strategy, resident = request.param
+    path = str(tmp_path_factory.mktemp(f"trace_{strategy}"))
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("REPRO_SANITIZE", "1")
         clear_sync_log()
         with jax.profiler.trace(path):
-            losses, allocs, backend = _drive(request.param)
+            losses, allocs, backend = _drive(strategy, resident)
         log = sync_log()
         clear_sync_log()
-    return {"strategy": request.param, "spans": _spans(path), "log": log,
-            "losses": losses, "allocs": allocs, "backend": backend}
+    return {"strategy": strategy, "resident": resident,
+            "spans": _spans(path), "log": log, "losses": losses,
+            "allocs": allocs, "backend": backend}
 
 
 def _inside(spans, outer):
@@ -115,16 +126,40 @@ def test_one_round_span_per_event_holds_every_host_step(traced):
         places = [x for x in _inside(spans, r) if x[0] == "bpt.place"]
         merge = [x for x in _inside(spans, r) if x[0] == "bpt.merge"]
         assert len(merge) == 1 and merge[0][3]["version"] == r[3]["round"]
-        if traced["strategy"] == "sgwu":
+        if traced["strategy"] == "sgwu" and traced["resident"]:
             assert len(draws) == len(places) == 1
-            assert draws[0][3] == {"nodes": M, "steps": H, "batch": B}
+            # the round's batch is gathered on the device and placing it
+            # moves nothing
+            assert draws[0][3] == {"nodes": M, "steps": H, "batch": B,
+                                   "resident": 1}
+            assert places[0][3]["bytes"] == 0
+        elif traced["strategy"] == "sgwu":
+            assert len(draws) == len(places) == 1
+            assert draws[0][3] == {"nodes": M, "steps": H, "batch": B,
+                                   "resident": 0}
             # images (B, 8, 8, 3) float32 and int labels, for m x h draws
             assert places[0][3]["bytes"] == M * H * B * (8 * 8 * 3 * 4 + 4)
         else:
             assert len(draws) == len(places) == H
-            assert draws[0][3] == {"nodes": 1, "steps": 1, "batch": B}
+            assert draws[0][3] == {"nodes": 1, "steps": 1, "batch": B,
+                                   "resident": 0}
             # one step's batch and its step scalar
             assert places[0][3]["bytes"] == B * (8 * 8 * 3 * 4 + 4) + 4
+
+
+def test_the_stacked_engine_places_the_set_once_before_the_first_round(
+        traced):
+    """VmapEngine uploads the whole training set once, outside every
+    round, where the set fits; the host draw and the AGWU heap engine
+    upload nothing before the first round."""
+    spans = traced["spans"]
+    first = min(s[1] for s in spans if s[0] == "bpt.round")
+    before = [s for s in spans if s[0] == "bpt.place" and s[1] < first]
+    if traced["resident"]:
+        n = 64 * M * 2           # images (n, 8, 8, 3) f32, int32 labels
+        assert [s[3]["bytes"] for s in before] == [n * (8 * 8 * 3 * 4 + 4)]
+    else:
+        assert before == []
 
 
 def test_idpa_span_marks_each_event_that_moves_the_allocation(traced):
@@ -146,7 +181,7 @@ def test_sync_spans_and_the_audit_log_count_the_same_pulls(traced):
 
 
 def test_attaching_the_profiler_changes_no_loss(traced):
-    losses, _, _ = _drive(traced["strategy"])
+    losses, _, _ = _drive(traced["strategy"], traced["resident"])
     for a, b in zip(losses, traced["losses"]):
         np.testing.assert_array_equal(a, b)
 
