@@ -577,8 +577,8 @@ class _SequentialState:
 class SequentialEngine(OuterEngine):
     """Legacy emulation: one jitted step per node per local step.
 
-    Kept as the reference the fused path is regression-tested against
-    (and the baseline ``benchmarks/outer_loop.py`` measures)."""
+    Kept only as the equivalence reference the tests hold the fused path
+    to."""
     backend = "sequential"
     strategy = "sgwu"
 
@@ -884,7 +884,7 @@ class HeapDeviceEngine(HeapEngine):
 
 
 # ----------------------------------------------------------------------
-# engine selection by name (drivers / benchmarks)
+# engine selection by name (drivers, bench/ and tests)
 # ----------------------------------------------------------------------
 ENGINES = {
     "scan": ScanEngine,

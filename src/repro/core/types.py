@@ -149,10 +149,9 @@ class TrainConfig:
     remat: bool = False
     # Fuse the m-node outer layer into ONE vmapped+scanned jitted dispatch
     # per SGWU round (node-stacked params/opt-states) instead of the
-    # sequential per-node Python loop.  False keeps the legacy loop — the
-    # numerical-equivalence regression tests and the outer_loop benchmark
-    # compare the two.  AGWU is unaffected (its event order IS the
-    # algorithm).
+    # sequential per-node Python loop.  False keeps the legacy loop, which
+    # the numerical-equivalence regression tests compare against.  AGWU is
+    # unaffected (its event order IS the algorithm).
     fused_outer: bool = True
     # --- device-sharded outer layer ---
     # Place the node axis on a real device mesh (launch/mesh.py `nodes`

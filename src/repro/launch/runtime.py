@@ -1,13 +1,14 @@
-"""Process-level runtime knobs shared by drivers and benchmarks.
+"""Process-level runtime knobs shared by the drivers.
 
-Currently one knob: JAX's persistent compilation cache.  Drivers and
-benchmarks call ``maybe_enable_compilation_cache`` once at start-up, so a
-repeat run of the same program reads its compiled executables back
-instead of compiling again.  Where ``JAX_COMPILATION_CACHE_DIR`` is set,
-JAX already uses that directory and nothing here overrides it; otherwise
-the cache lives at one fixed path inside the checkout (``.jax_cache/``,
-gitignored) — a fixed path, because the directory is part of what makes
-a later run find the entries.  Of the tests, only ``tests/test_runtime.py``
+Currently one knob: JAX's persistent compilation cache.  The drivers
+(``launch.train``, ``launch.serve``, ``examples/train_bpt_cnn.py`` and
+``chip_smoke.py``) call ``maybe_enable_compilation_cache`` once at
+start-up, so a repeat run of the same program reads its compiled
+executables back instead of compiling again.  Where
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses that directory and
+nothing here overrides it; otherwise the cache lives at one fixed path
+inside the checkout (``.jax_cache/``, gitignored) — a fixed path, because
+the directory is part of what makes a later run find the entries.  Of the tests, only ``tests/test_runtime.py``
 calls it, and it restores the config afterwards, so the suite keeps its
 hermetic no-cache behavior.
 """

@@ -21,6 +21,8 @@ Covered contracts:
 import dataclasses
 import json
 import os
+import tempfile
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -93,21 +95,21 @@ def _assert_trees_equal(a, b):
 class TestRoundTripProperties:
     @settings(max_examples=25, deadline=None)
     @given(seed=st.integers(0, 2**31 - 1))
-    def test_save_restore_round_trips_bit_exactly(self, seed, tmp_path):
-        path = tmp_path / str(seed)      # one dir per drawn example
-        tree = make_tree(seed)
-        step = seed % 1000
-        checkpoint.save(str(path), tree, step=step)
-        restored, got = checkpoint.restore(str(path), _zeros_like(tree),
-                                           step=step)
-        assert got == step
-        _assert_trees_equal(tree, restored)
-        # atomicity: a completed save leaves no temp strays behind
-        assert not [f for f in os.listdir(path) if f.endswith(".tmp")]
+    def test_save_restore_round_trips_bit_exactly(self, seed):
+        with tempfile.TemporaryDirectory() as path:
+            tree = make_tree(seed)
+            step = seed % 1000
+            checkpoint.save(path, tree, step=step)
+            restored, got = checkpoint.restore(path, _zeros_like(tree),
+                                               step=step)
+            assert got == step
+            _assert_trees_equal(tree, restored)
+            # atomicity: a completed save leaves no temp strays behind
+            assert not [f for f in os.listdir(path) if f.endswith(".tmp")]
 
     @settings(max_examples=25, deadline=None)
     @given(seed=st.integers(0, 2**31 - 1))
-    def test_state_round_trip_preserves_scalars(self, seed, tmp_path):
+    def test_state_round_trip_preserves_scalars(self, seed):
         tree = make_tree(seed)
         rng = np.random.default_rng(seed)
         scalars = {"clock": float(rng.random() * 100),
@@ -116,32 +118,34 @@ class TestRoundTripProperties:
                    "down": sorted(int(x) for x in
                                   rng.integers(0, 8, size=2)),
                    "nested": {"epoch": [1, 2, 3], "label": "run"}}
-        path = tmp_path / str(seed)
-        checkpoint.save_state(str(path), tree, seed % 1000, scalars)
-        arrays, got_scalars, _ = checkpoint.restore_state(
-            str(path), _zeros_like(tree))
+        with tempfile.TemporaryDirectory() as path:
+            checkpoint.save_state(path, tree, seed % 1000, scalars)
+            arrays, got_scalars, _ = checkpoint.restore_state(
+                path, _zeros_like(tree))
         _assert_trees_equal(tree, arrays)
         # scalars survive the JSON round trip verbatim
         assert json.loads(json.dumps(scalars)) == got_scalars
 
     @settings(max_examples=15, deadline=None)
     @given(seed=st.integers(0, 2**31 - 1))
-    def test_latest_step_picks_max_and_ignores_strays(self, seed, tmp_path):
-        path = tmp_path / str(seed)
+    def test_latest_step_picks_max_and_ignores_strays(self, seed):
         rng = np.random.default_rng(seed)
         steps = sorted({int(s) for s in rng.integers(0, 5000, size=4)})
         tree = {"w": np.ones(2, np.float32)}
-        for s in steps:
-            checkpoint.save(str(path), tree, step=s)
-        # strays that must all be invisible to latest_step(kind="ckpt")
-        (path / "ckpt_99999999.npz.tmp").write_bytes(b"partial")
-        (path / "notes.txt").write_text("hi")
-        (path / "ckpt_abc.npz").write_bytes(b"junk")
-        checkpoint.save(str(path), tree, step=7777, kind="state")
-        assert checkpoint.latest_step(str(path)) == steps[-1]
-        assert checkpoint.latest_step(str(path), kind="state") == 7777
-        restored, got = checkpoint.restore(str(path), _zeros_like(tree))
-        assert got == steps[-1]
+        with tempfile.TemporaryDirectory() as tmp:
+            path = Path(tmp)
+            for s in steps:
+                checkpoint.save(str(path), tree, step=s)
+            # strays that must all be invisible to latest_step(kind="ckpt")
+            (path / "ckpt_99999999.npz.tmp").write_bytes(b"partial")
+            (path / "notes.txt").write_text("hi")
+            (path / "ckpt_abc.npz").write_bytes(b"junk")
+            checkpoint.save(str(path), tree, step=7777, kind="state")
+            assert checkpoint.latest_step(str(path)) == steps[-1]
+            assert checkpoint.latest_step(str(path), kind="state") == 7777
+            restored, got = checkpoint.restore(str(path),
+                                               _zeros_like(tree))
+            assert got == steps[-1]
 
 
 # ----------------------------------------------------------------------

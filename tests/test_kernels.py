@@ -26,6 +26,7 @@ class TestConv2d:
         (2, 16, 16, 3, 8, 3),
         (2, 12, 12, 4, 16, 5),
         (1, 7, 9, 2, 4, 3),          # odd spatial
+        (4, 16, 16, 8, 16, 3),       # the fwd+bwd gate's shape
     ])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_matches_ref(self, B, H, W, Cin, Cout, k, dtype):
@@ -83,6 +84,7 @@ class TestConv2dGrad:
         (6, "SAME", 0, 1, (2, 6, 6, 4, 4)),     # 1x1 conv
         (7, "SAME", 0, 2, (2, 8, 8, 3, 8)),     # even k: asymmetric pads
         (8, "SAME", 4, 4, (1, 8, 8, 2, 8)),     # even k, tiled
+        (9, "SAME", 0, 3, (4, 16, 16, 8, 16)),  # 4-image batch, 8 -> 16
     ]
 
     @pytest.mark.parametrize("seed,padding,oc_tile,k,shape", GRID)

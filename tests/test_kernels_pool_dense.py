@@ -36,6 +36,7 @@ class TestMaxPool2d:
         (2, 12, 12, 4, 4),
         (1, 9, 7, 2, 2),           # odd spatial: remainder dropped
         (2, 8, 8, 3, 8),           # window == whole map
+        (8, 32, 32, 16, 2),        # 8 images of 32x32x16
     ]
 
     @pytest.mark.parametrize("B,H,W,C,window", SHAPES)
@@ -127,6 +128,8 @@ def _dense_grid():
         (11, 512, "relu", True, (256, 1400, 1400)),
         # ... and in dw (K = 1400 samples, the rows of x^T)
         (12, 512, "none", False, (1400, 256, 768)),
+        # an Alg. 4.2-style block: 64 of 512 neurons
+        (13, 64, "relu", True, (32, 256, 512)),
     ]
 
 

@@ -219,7 +219,7 @@ class TestSingleDecisionPoint:
         offenders = [
             f"{Path(f.path).relative_to(REPO)}:{f.line}"
             for f in lint_paths(
-                [str(REPO / d) for d in ("src", "tests", "benchmarks")],
+                [str(REPO / d) for d in ("src", "tests", "examples")],
                 only=["RPL402"])
         ]
         assert not offenders, \
@@ -396,7 +396,7 @@ class TestCompileBudgets:
 
     def test_decode_steady_state_meets_zero_budget(self, dense_setup):
         """After warmup, the decode loop must dispatch from cache — the
-        compile_budget(0) contract the benchmark harness also pins."""
+        steady-state compile_budget(0) contract."""
         from repro.sanitize import compile_budget
         cfg, params = dense_setup
         eng = make_serve_engine(params, cfg, ServeConfig(slots=2,

@@ -5,7 +5,7 @@ dispatch decision points, trace hygiene for Eq. 8 timing, the kernel
 custom_vjp/fallback contracts, deprecation bans, donation safety) with
 a stdlib-``ast`` rule engine.  Run it as::
 
-    python -m tools.reprolint src tests benchmarks examples
+    python -m tools.reprolint src tests examples
 
 See docs/LINTING.md for the rule catalogue.
 """

@@ -12,7 +12,7 @@ from .engine import FileContext, Project, _select, iter_py_files, \
     render_json, render_text, run_rules
 from .rules import ALL_RULES
 
-DEFAULT_PATHS = ("src", "tests", "benchmarks", "examples")
+DEFAULT_PATHS = ("src", "tests", "examples")
 
 
 def main(argv=None) -> int:
